@@ -31,6 +31,17 @@ import numpy as np
 
 from benchmarks.common import emit, timed
 
+# The chip whose published peaks price calibrated τ when this runs off-TPU
+# (launch.costs.PEAKS); on a TPU each server's own device kind is used.
+MODELLED_DEVICE_KIND = "TPU v5 lite"
+
+
+def _tau_device_kind():
+    import jax
+
+    return None if jax.default_backend() == "tpu" else MODELLED_DEVICE_KIND
+
+
 # per-family relative block compute weights of the hybrid scenario: a
 # shared-attention block (mamba mixer + width-2d attention+MLP) costs ~2.5x
 # a plain mamba mixer; weights average ~1 so totals stay comparable to the
@@ -392,7 +403,8 @@ def shard_decode_throughput(n_sessions: int = 8, n_rounds: int = 4,
         out[tag] = n_sessions * n_rounds / dt
         toks[tag] = [list(system.sessions[s].tokens) for s in sids]
         if tag == "sharded":
-            tau_cal = float(min(system.calibrate_taus().values()))
+            tau_cal = float(min(
+                system.calibrate_taus(_tau_device_kind()).values()))
             group_chips = [system.servers[j].n_chips
                            for j in sorted(system.servers)]
     assert toks["sharded"] == toks["twin"], \
@@ -477,7 +489,7 @@ def hetero_validation(n_sessions: int = 6, n_rounds: int = 4,
         toks[tag] = [list(system.sessions[s].tokens) for s in sids]
         vts[tag] = [float(system.sessions[s].virtual_time) for s in sids]
         if tag == "hetero":
-            taus = system.calibrate_taus()
+            taus = system.calibrate_taus(_tau_device_kind())
             chips = [system.servers[j].n_chips
                      for j in sorted(system.servers)]
     assert toks["hetero"] == toks["twin"], \
@@ -533,9 +545,21 @@ def hetero_validation(n_sessions: int = 6, n_rounds: int = 4,
 
 
 def _hetero_rows(smoke: bool = False):
-    """Parent-side driver for :func:`hetero_validation`: spawn a fresh
+    """Rows of :func:`hetero_validation`.  Off-TPU: spawn a fresh
     interpreter with 8 forced host CPU devices (this process's jax device
-    count is frozen at first import) and parse the child's JSON rows."""
+    count is frozen at first import) and parse the child's JSON rows.  On a
+    TPU host this process holds the chips, so a child could not reach them:
+    the rows run here when the host has the 8 devices they need, and are
+    otherwise not produced (None, with a line saying so)."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        if len(jax.devices()) >= 8:
+            return hetero_validation(n_rounds=2 if smoke else 4)
+        print(f"hetero.decode.tput / optgap.hetero not produced: they need "
+              f"8 devices, this TPU host has {len(jax.devices())}")
+        return None
+
     import subprocess
     import sys
 
@@ -1071,23 +1095,24 @@ def run(full: bool = False, smoke: bool = False):
 
     # heterogeneous device groups: a {solo, (1,2), (2,2)} fleet vs the
     # all-solo twin (token + virtual-clock parity asserted when measured)
-    # and the calibrated-vs-uniform τ CG-BP placement gap.  Runs in a
-    # fresh interpreter because this process's jax device count is frozen
-    # at first import and the matrix needs 8 forced host devices.
+    # and the calibrated-vs-uniform τ CG-BP placement gap.  Off-TPU it runs
+    # in a fresh interpreter because this process's jax device count is
+    # frozen at first import and the matrix needs 8 forced host devices.
     rows, us = timed(_hetero_rows, smoke=smoke)
-    het, og = rows["hetero.decode.tput"], rows["optgap.hetero"]
-    emit("hetero.decode.tput", us,
-         f"hetero={het['hetero_tok_s']:.0f} tok/s "
-         f"twin={het['twin_tok_s']:.0f} tok/s ratio={het['ratio']:.2f}x "
-         f"chips={het['group_chips']} "
-         f"tau_spread={het['tau_spread']:.2f}x")
-    _record("hetero.decode.tput", **het)
-    emit("optgap.hetero", 0.0,
-         f"calibrated={og['cost_calibrated_s']*1e3:.1f}ms "
-         f"uniform={og['cost_uniform_s']*1e3:.1f}ms "
-         f"gap={og['optgap_frac']*100:.0f}% "
-         f"placement_differs={og['placement_differs']}")
-    _record("optgap.hetero", **og)
+    if rows is not None:
+        het, og = rows["hetero.decode.tput"], rows["optgap.hetero"]
+        emit("hetero.decode.tput", us,
+             f"hetero={het['hetero_tok_s']:.0f} tok/s "
+             f"twin={het['twin_tok_s']:.0f} tok/s "
+             f"ratio={het['ratio']:.2f}x chips={het['group_chips']} "
+             f"tau_spread={het['tau_spread']:.2f}x")
+        _record("hetero.decode.tput", **het)
+        emit("optgap.hetero", 0.0,
+             f"calibrated={og['cost_calibrated_s']*1e3:.1f}ms "
+             f"uniform={og['cost_uniform_s']*1e3:.1f}ms "
+             f"gap={og['optgap_frac']*100:.0f}% "
+             f"placement_differs={og['placement_differs']}")
+        _record("optgap.hetero", **og)
 
     # paged cache pools: co-residency headline (the same topology's
     # worst-case budget caps slab at 1/4 of the cohort) + the
@@ -1319,6 +1344,9 @@ if __name__ == "__main__":
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "BENCH_engine.json"), help="output path for the JSON metrics")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if args.hetero_child:
         print(json.dumps(hetero_validation(
             n_rounds=2 if args.smoke else 4)))

@@ -102,7 +102,7 @@ def run(full: bool = False):
         k = jnp.asarray(rng.randn(B, S, Kv, D), jnp.float32) * 0.3
         v = jnp.asarray(rng.randn(B, S, Kv, D), jnp.float32) * 0.3
         flash_run = lambda: flash_attention(
-            q, k, v, causal=True, block_q=64, block_kv=64, interpret=True
+            q, k, v, causal=True, block_q=64, block_kv=64
         ).block_until_ready()
         flash_run()  # warm-up: the timed call measures steady state
         out, us = timed(flash_run)
@@ -122,7 +122,7 @@ def run(full: bool = False):
     ck = jnp.asarray(rng.randn(B, T, Kv, D), jnp.float32) * 0.3
     cv = jnp.asarray(rng.randn(B, T, Kv, D), jnp.float32) * 0.3
     decode_run = lambda: decode_attention(
-        q, ck, cv, T - 1, block_kv=256, interpret=True).block_until_ready()
+        q, ck, cv, T - 1, block_kv=256).block_until_ready()
     decode_run()  # warm-up: the timed call measures steady state
     out, us = timed(decode_run)
     G = H // Kv

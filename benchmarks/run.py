@@ -17,9 +17,10 @@ def main() -> None:
     ap.add_argument("--only", default="")
     args, _ = ap.parse_known_args()
 
-    from benchmarks import (fig_sweeps, optimality_gap, roofline,
-                            table4_clustered, table5_scattered,
-                            table6_runtime)
+    # a suite that cannot be imported fails the run here, loudly
+    from benchmarks import (engine_validation, fig_sweeps, kernel_bench,
+                            optimality_gap, roofline, table4_clustered,
+                            table5_scattered, table6_runtime)
     suites = [
         ("table4", table4_clustered.run),
         ("table5", table5_scattered.run),
@@ -27,17 +28,9 @@ def main() -> None:
         ("figs", fig_sweeps.run),
         ("optgap", optimality_gap.run),
         ("roofline", roofline.run),
+        ("engine_validation", engine_validation.run),
+        ("kernels", kernel_bench.run),
     ]
-    try:
-        from benchmarks import engine_validation
-        suites.append(("engine_validation", engine_validation.run))
-    except ImportError:
-        pass
-    try:
-        from benchmarks import kernel_bench
-        suites.append(("kernels", kernel_bench.run))
-    except ImportError:
-        pass
 
     only = set(args.only.split(",")) if args.only else None
     failures = 0

@@ -12,8 +12,8 @@ Each wrapper ships a ``*_unsupported(**features) -> Optional[str]``
 predicate naming the feature it cannot serve (the serving backend layer's
 XLA-fallback dispatch test); calling a wrapper with an unsupported feature
 raises ``ValueError`` instead of returning wrong numbers.  Shared runtime
-knobs (interpret-mode default incl. the ``REPRO_PALLAS_INTERPRET``
-override, backend-name validation) live in ``repro.kernels.runtime``.
+knobs (interpret mode off-TPU, backend-name validation, the counted
+per-call kernel-or-fallback decision) live in ``repro.kernels.runtime``.
 """
 from repro.kernels.decode_attention import (decode_attention,
                                             decode_attention_ref,

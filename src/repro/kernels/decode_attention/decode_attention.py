@@ -10,7 +10,7 @@ caller-supplied faithful softmax scale.
 The masking semantics mirror ``models.attention.decode_attention_xla``
 exactly — the contract the pooled serving steps dispatch on:
 
-* ``pos`` is PER ROW (shape ``(BKv,)`` in SMEM, indexed by
+* ``pos`` is PER ROW (shape ``(1, BKv)`` in SMEM, indexed by
   ``program_id(0)``): pooled cache rows decode at different positions.
 * causal + sliding-window: valid iff ``0 <= pos - kv_pos < window``
   (``window`` is a dynamic scalar — gemma3's local:global pattern makes it
@@ -59,9 +59,9 @@ def _kernel(pos_ref, kvl_ref, win_ref, *rest, block_kv, group, causal,
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
 
-    pos = pos_ref[b]
-    kvl = kvl_ref[b]
-    win = win_ref[0]
+    pos = pos_ref[0, b]
+    kvl = kvl_ref[0, b]
+    win = win_ref[0, 0]
     kv_start = ki * block_kv
 
     if causal:
@@ -84,8 +84,7 @@ def _kernel(pos_ref, kvl_ref, win_ref, *rest, block_kv, group, causal,
             jnp.int32, (group, block_kv), 1)
         diff = pos - kv_pos
         if has_slopes:
-            s = s + slopes_ref[0][:, None] * (
-                -jnp.abs(diff).astype(jnp.float32))
+            s = s + slopes_ref[0] * (-jnp.abs(diff).astype(jnp.float32))
         ok = kv_pos < kvl
         if causal:
             ok &= (diff >= 0) & (diff < win)
@@ -134,19 +133,23 @@ def decode_attention_bkv(q, k, v, pos, *, kv_len=None, window=None,
         _kernel, block_kv=block_kv, group=G, causal=causal,
         has_slopes=slopes is not None,
         scale=float(scale) if scale is not None else 1.0 / np.sqrt(Dk))
-    pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1),
-                               (BKv,))
+    # SMEM scalars as (1, n) rows: under vmap (the pooled steps' per-row
+    # idiom) each gains a leading batch dim, and its whole-array block then
+    # still spans both trailing dims, as Mosaic requires
+    pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(1, -1),
+                               (1, BKv))
     kvl_arr = jnp.broadcast_to(
-        jnp.asarray(T if kv_len is None else kv_len, jnp.int32).reshape(-1),
-        (BKv,))
+        jnp.asarray(T if kv_len is None else kv_len,
+                    jnp.int32).reshape(1, -1), (1, BKv))
     win_arr = jnp.asarray(NO_WINDOW if window is None else window,
-                          jnp.int32).reshape(1)
+                          jnp.int32).reshape(1, 1)
     scalar_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] * 3
     inputs = [pos_arr, kvl_arr, win_arr]
     slope_specs = []
     if slopes is not None:
-        slope_specs = [pl.BlockSpec((1, G), lambda b, ki: (b, 0))]
-        inputs.append(jnp.asarray(slopes, jnp.float32))
+        # (BKv, G, 1): the block spans both trailing dims, as Mosaic requires
+        slope_specs = [pl.BlockSpec((1, G, 1), lambda b, ki: (b, 0, 0))]
+        inputs.append(jnp.asarray(slopes, jnp.float32)[:, :, None])
     out = pl.pallas_call(
         kern,
         grid=(BKv, n_kv),

@@ -1,15 +1,13 @@
 """Shared runtime knobs for the Pallas serving kernels.
 
-One module owns the two decisions every kernel wrapper used to make for
+One module owns the decisions every kernel wrapper used to make for
 itself (four copy-pasted ``_default_interpret`` helpers before this file
 existed — a backend change could silently drift per kernel):
 
 * ``default_interpret()`` — whether ``pallas_call`` should run in interpret
-  mode.  Off-TPU backends (the CPU CI/container) must interpret; real TPUs
-  compile through Mosaic.  The ``REPRO_PALLAS_INTERPRET`` environment
-  variable overrides the platform probe (``1``/``true`` forces interpret,
-  ``0``/``false`` forces compiled) so CI jobs pin a deterministic mode
-  regardless of the host.
+  mode.  Off-TPU backends (the CPU test suite) must interpret; a TPU always
+  compiles through Mosaic — nothing can force interpret mode there, so a
+  run on the chip never times the interpreter by accident.
 * ``resolve_backend()`` — validation for the engine-facing compute-backend
   switch (``backend="xla" | "pallas"``) threaded from
   ``serving.GeoServingSystem`` through the pooled step factories down to
@@ -17,10 +15,13 @@ existed — a backend change could silently drift per kernel):
   runs everywhere); ``"pallas"`` routes supported block computations
   through ``repro.kernels`` and falls back to the oracle per call site via
   the kernels' own ``*_unsupported`` dispatch predicates.
+* ``use_kernel()`` — that per-call decision, which counts every fallback
+  in ``XLA_FALLBACKS`` so a run can assert that none happened.
 """
 from __future__ import annotations
 
-import os
+import collections
+from typing import Optional
 
 import jax
 
@@ -34,7 +35,24 @@ BACKENDS = ("xla", "pallas")
 # per-kernel copies could drift and silently change window semantics.
 NO_WINDOW = 1 << 30
 
-_INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
+# Calls under backend="pallas" that a kernel's ``*_unsupported`` predicate
+# sent to the XLA path instead, keyed by "kernel: reason".  Dispatch is
+# decided while a step is traced, so a jitted step counts once per trace,
+# not once per execution.  Process-wide, like jax's own trace caches: a
+# run that must prove every call reached a kernel reads it (chip_smoke.py).
+XLA_FALLBACKS: collections.Counter = collections.Counter()
+
+
+def use_kernel(backend: str, kernel: str, reason: Optional[str]) -> bool:
+    """Whether a block call runs ``kernel``: the backend asks for Pallas and
+    the kernel's predicate gave no ``reason`` against the call.  A Pallas
+    call that falls back to XLA is counted in :data:`XLA_FALLBACKS`."""
+    if resolve_backend(backend) != "pallas":
+        return False
+    if reason is not None:
+        XLA_FALLBACKS[f"{kernel}: {reason}"] += 1
+        return False
+    return True
 
 
 def resolve_backend(backend: str) -> str:
@@ -47,14 +65,6 @@ def resolve_backend(backend: str) -> str:
 
 
 def default_interpret() -> bool:
-    """Interpret-mode default for ``pallas_call``.
-
-    ``REPRO_PALLAS_INTERPRET`` (when set and non-empty) wins: ``0``/
-    ``false`` force compiled execution, anything else forces interpret —
-    the CI determinism hook.  Otherwise interpret iff the default jax
-    backend is not a TPU (Pallas TPU kernels cannot lower elsewhere).
-    """
-    env = os.environ.get(_INTERPRET_ENV)
-    if env is not None and env != "":
-        return env.lower() not in ("0", "false", "no")
+    """Interpret-mode default for ``pallas_call``: interpret iff the default
+    jax backend is not a TPU (Pallas TPU kernels cannot lower elsewhere)."""
     return jax.default_backend() != "tpu"

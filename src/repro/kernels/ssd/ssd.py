@@ -26,8 +26,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, d_ref, s0_ref, o_ref,
+def _kernel(a_ref, d_ref, x_ref, b_ref, c_ref, dt_ref, s0_ref, o_ref,
             sout_ref, state, *, chunk, p, n):
+    bh = pl.program_id(0)
     ci = pl.program_id(1)
     n_chunks = pl.num_programs(1)
 
@@ -38,35 +39,48 @@ def _kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, d_ref, s0_ref, o_ref,
     x = x_ref[0].astype(jnp.float32)  # (Q, p)
     Bm = b_ref[0].astype(jnp.float32)  # (Q, n)
     Cm = c_ref[0].astype(jnp.float32)  # (Q, n)
-    dt = dt_ref[0].astype(jnp.float32)  # (Q,)
-    A = a_ref[0]  # scalar (negative)
-    D = d_ref[0]
+    dt = dt_ref[0].astype(jnp.float32)  # (Q, 1)
+    A = a_ref[0, bh]  # scalar (negative), from SMEM
+    D = d_ref[0, bh]
 
-    la = dt * A  # (Q,) log decay per step
-    seg = jnp.cumsum(la)  # inclusive
+    la = dt * A  # (Q, 1) log decay per step
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tril = (row >= col).astype(jnp.float32)
+    # Mosaic has no cumsum and cannot broadcast a (1, 1) value: every sum
+    # over steps is a matmul against a 0/1 matrix, at full f32 precision
+    # since the decays compound over it.
+    def steps_sum(lhs, rhs, dims):
+        return jax.lax.dot_general(lhs, rhs, (dims, ((), ())),
+                                   precision=jax.lax.Precision.HIGHEST,
+                                   preferred_element_type=jnp.float32)
+
+    seg = steps_sum(tril, la, ((1,), (0,)))  # inclusive, column (Q, 1)
+    seg_row = steps_sum(la, tril, ((0,), (1,)))  # the same as a row (1, Q)
+    # seg_end - seg[t] = sum over the steps after t
+    to_end = steps_sum((row < col).astype(jnp.float32), la, ((1,), (0,)))
+    # seg_end, broadcast down the state's p rows: (p, 1)
+    total = steps_sum(jnp.ones((p, chunk), jnp.float32), la, ((1,), (0,)))
     # intra-chunk: Y[t] = sum_{i<=t} exp(seg[t]-seg[i]) (C_t·B_i) dt_i x_i
     G = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (Q, Q)
     # exponents clamped <= 0 (masked upper-triangle entries would be inf)
-    decay = jnp.exp(jnp.minimum(seg[:, None] - seg[None, :], 0.0))
-    mask = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) \
-        >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    M = jnp.where(mask, G * decay, 0.0)
-    xb = x * dt[:, None]
+    decay = jnp.exp(jnp.minimum(seg - seg_row, 0.0))
+    M = jnp.where(row >= col, G * decay, 0.0)
+    xb = x * dt
     y = jax.lax.dot_general(M, xb, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (Q, p)
     # inter-chunk: Y[t] += C_t · (exp(seg[t]) state_in)   (state is (p, n))
-    y = y + jnp.exp(seg)[:, None] * jax.lax.dot_general(
+    y = y + jnp.exp(seg) * jax.lax.dot_general(
         Cm, state[...], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     y = y + x * D
     o_ref[0] = y.astype(o_ref.dtype)
     # state update
-    decay_to_end = jnp.exp(seg[-1] - seg)  # (Q,)
     contrib = jax.lax.dot_general(
-        (xb * decay_to_end[:, None]), Bm, (((0,), (0,)), ((), ())),
+        (xb * jnp.exp(to_end)), Bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)  # (p, n)
-    state[...] = jnp.exp(seg[-1]) * state[...] + contrib
+    state[...] = jnp.exp(total) * state[...] + contrib
 
     @pl.when(ci == n_chunks - 1)
     def _finish():
@@ -101,12 +115,16 @@ def ssd_bh(x, Bm, Cm, dt, A, D, state_in=None, *, chunk: int = 64,
         kern,
         grid=(BH, n_chunks),
         in_specs=[
+            # per-(batch, head) scalars A and D whole in SMEM, as (1, BH)
+            # rows so that a vmapped call's blocks still span both
+            # trailing dims
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, chunk, p), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, chunk, n), lambda bh, ci, H=H: (bh // H, ci, 0)),
             pl.BlockSpec((1, chunk, n), lambda bh, ci, H=H: (bh // H, ci, 0)),
-            pl.BlockSpec((1, chunk), lambda bh, ci: (bh, ci)),
-            pl.BlockSpec((1,), lambda bh, ci: (bh,)),
-            pl.BlockSpec((1,), lambda bh, ci: (bh,)),
+            # dt as a column (BH, S, 1): the block spans the trailing dim
+            pl.BlockSpec((1, chunk, 1), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, p, n), lambda bh, ci: (bh, 0, 0)),
         ],
         out_specs=[
@@ -119,5 +137,6 @@ def ssd_bh(x, Bm, Cm, dt, A, D, state_in=None, *, chunk: int = 64,
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(x, Bm, Cm, dt, A, D, state_in.astype(jnp.float32))
+    )(A.astype(jnp.float32)[None], D.astype(jnp.float32)[None], x, Bm, Cm,
+      dt[:, :, None], state_in.astype(jnp.float32))
     return out[:, :S], state_out

@@ -37,27 +37,41 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sout_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     lw = lw_ref[0].astype(jnp.float32)  # log decay, clamped <= 0
-    u = u_ref[0].astype(jnp.float32)  # (hd,)
+    u = u_ref[0].astype(jnp.float32)  # (1, hd)
 
-    seg = jnp.cumsum(lw, axis=0)  # inclusive (Q, hd)
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # Mosaic has no cumsum: the inclusive prefix sum is a lower-triangular
+    # matmul, at full f32 precision since the decays compound over it
+    seg = jax.lax.dot_general(
+        (row >= col).astype(jnp.float32), lw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)  # inclusive (Q, hd)
     segx = seg - lw  # exclusive
+    seg_end = seg[chunk - 1:chunk]  # (1, hd)
     # intra-chunk: A[t,i] = sum_c r[t,c] k[i,c] exp(segx[t,c]-seg[i,c]), i<t
     # exponents clamped <= 0 (masked upper-triangle entries would be inf)
     decay = jnp.exp(jnp.minimum(segx[:, None, :] - seg[None, :, :], 0.0))
-    mask = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) \
-        > jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    A = jnp.einsum("tc,ic,tic->ti", r, k, decay)
-    A = jnp.where(mask, A, 0.0)
-    out = A @ v
+    A = jnp.sum(r[:, None, :] * k[None, :, :] * decay, axis=2)
+    A = jnp.where(row > col, A, 0.0)
+    out = jax.lax.dot_general(A, v, (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)
     # bonus (current token): (r_t · (u ⊙ k_t)) v_t
-    bonus = jnp.sum(r * u[None, :] * k, axis=1)  # (Q,)
-    out = out + bonus[:, None] * v
+    bonus = jnp.sum(r * u * k, axis=1, keepdims=True)  # (Q, 1)
+    out = out + bonus * v
     # inter-chunk: r_t ⊙ exp(segx_t) against the carried state
-    out = out + (r * jnp.exp(segx)) @ state[...]
+    out = out + jax.lax.dot_general(
+        r * jnp.exp(segx), state[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
     o_ref[0] = out.astype(o_ref.dtype)
     # state update: S <- diag(prod w) S + sum_i (k_i ⊙ exp(seg_end - seg_i)) v_i^T
-    decay_to_end = jnp.exp(seg[-1][None, :] - seg)  # (Q, hd)
-    state[...] = (jnp.exp(seg[-1])[:, None] * state[...]
+    # prod w as a column (hd, 1): the chunk's total log decay, lw^T · 1
+    total = jax.lax.dot_general(
+        lw, jnp.ones((chunk, 1), jnp.float32), (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    decay_to_end = jnp.exp(seg_end - seg)  # (Q, hd)
+    state[...] = (jnp.exp(total) * state[...]
                   + jax.lax.dot_general(
                       (k * decay_to_end), v, (((0,), (0,)), ((), ())),
                       preferred_element_type=jnp.float32))
@@ -97,7 +111,8 @@ def wkv6_bh(r, k, v, lw, u, state_in=None, *, chunk: int = 16,
             pl.BlockSpec((1, chunk, hd), lambda b, ci: (b, ci, 0)),
             pl.BlockSpec((1, chunk, hd), lambda b, ci: (b, ci, 0)),
             pl.BlockSpec((1, chunk, hd), lambda b, ci: (b, ci, 0)),
-            pl.BlockSpec((1, hd), lambda b, ci: (b, 0)),
+            # u as (BH, 1, hd): the block spans both trailing dims
+            pl.BlockSpec((1, 1, hd), lambda b, ci: (b, 0, 0)),
             pl.BlockSpec((1, hd, hd), lambda b, ci: (b, 0, 0)),
         ],
         out_specs=[
@@ -110,5 +125,5 @@ def wkv6_bh(r, k, v, lw, u, state_in=None, *, chunk: int = 16,
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, lw, u, state_in.astype(jnp.float32))
+    )(r, k, v, lw, u[:, None, :], state_in.astype(jnp.float32))
     return out[:, :S], state_out

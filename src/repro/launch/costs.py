@@ -122,10 +122,7 @@ class CostSummary:
 
 
 def summarize_compiled(compiled) -> CostSummary:
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax <= 0.4.x: one dict per program
-        ca = ca[0] if ca else {}
-    ca = ca or {}
+    ca = compiled.cost_analysis() or {}
     colls = parse_collectives(compiled.as_text())
     return CostSummary(
         flops=float(ca.get("flops", 0.0) or 0.0),
@@ -154,13 +151,34 @@ def memory_summary(compiled) -> Dict:
 # Roofline terms
 # ---------------------------------------------------------------------------
 
-PEAK_FLOPS_BF16 = 197e12  # TPU v5e-class, per chip
-HBM_BW = 819e9  # bytes/s per chip
-ICI_BW = 50e9  # bytes/s per link (assignment constant)
+@dataclass(frozen=True)
+class ChipPeaks:
+    flops_bf16: float  # FLOP/s per chip
+    hbm_bw: float  # bytes/s per chip
+    ici_bw: float  # bytes/s per inter-chip link
+
+
+# Published per-chip peaks keyed by ``jax.Device.device_kind``.  TPU v5e:
+# Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s of ICI over 4 links (50 GB/s each).  JAX reports a
+# v5e as "TPU v5 lite".
+_V5E = ChipPeaks(flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9)
+PEAKS: Dict[str, ChipPeaks] = {"TPU v5 lite": _V5E, "TPU v5e": _V5E}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; a kind without published peaks is an
+    error, never a default (a CPU caller names the TPU kind it models)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)} — pass the target chip's kind") from None
 
 
 def tau_from_step_cost(cost: CostSummary, n_chips: int, m_blocks: int,
-                       n_rows: int) -> float:
+                       n_rows: int, *, device_kind: str) -> float:
     """Per-block per-token decode τ (s) from one pooled decode step's cost.
 
     The step advances every pool row one token through all ``m_blocks``
@@ -169,14 +187,15 @@ def tau_from_step_cost(cost: CostSummary, n_chips: int, m_blocks: int,
     eq. (1) multiplies back up.  With a sharded step the cost analysis is
     per-device after SPMD partitioning, so a TP/EP device group's speedup
     (and its collective bytes) land in τ automatically."""
-    terms = roofline_terms(cost, n_chips)
+    terms = roofline_terms(cost, n_chips, device_kind=device_kind)
     return terms["bound_s"] / max(1, int(m_blocks) * int(n_rows))
 
 
 def roofline_terms(cost: CostSummary, n_chips: int,
-                   mem_floor_bytes: float = 0.0) -> Dict:
+                   mem_floor_bytes: float = 0.0, *, device_kind: str) -> Dict:
     """cost_analysis numbers are PER-DEVICE after SPMD partitioning, so the
-    per-chip terms divide by the per-chip rates directly.
+    per-chip terms divide by the per-chip rates of ``device_kind``
+    (:data:`PEAKS`) directly.
 
     CPU-backend caveat (DESIGN.md §6): XLA:CPU has no native bf16 GEMMs, so it
     upcasts bf16 dots/gathers to f32 — ``bytes_accessed`` (and temp memory)
@@ -185,11 +204,13 @@ def roofline_terms(cost: CostSummary, n_chips: int,
     adjustment for bf16 programs, and an analytic floor (params+caches+
     outputs actually touched, from per-device argument/output sizes).
     """
-    compute_s = cost.flops / PEAK_FLOPS_BF16
-    memory_s = cost.bytes_accessed / HBM_BW
-    memory_s_tpu_est = max(cost.bytes_accessed / 2.0, mem_floor_bytes) / HBM_BW
-    memory_s_floor = mem_floor_bytes / HBM_BW
-    collective_s = cost.coll_wire_bytes / ICI_BW
+    peaks = chip_peaks(device_kind)
+    compute_s = cost.flops / peaks.flops_bf16
+    memory_s = cost.bytes_accessed / peaks.hbm_bw
+    memory_s_tpu_est = (max(cost.bytes_accessed / 2.0, mem_floor_bytes)
+                        / peaks.hbm_bw)
+    memory_s_floor = mem_floor_bytes / peaks.hbm_bw
+    collective_s = cost.coll_wire_bytes / peaks.ici_bw
     dominant = max(
         (("compute", compute_s), ("memory", memory_s),
          ("collective", collective_s)), key=lambda t: t[1])[0]

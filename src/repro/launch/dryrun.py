@@ -34,6 +34,10 @@ from repro.training.train_step import (  # noqa: E402
     TrainHParams, make_optimizer_for, make_train_step)
 
 
+# The production chip the fake-CPU lowering models: its roofline peaks
+# (launch.costs.PEAKS) price every cell.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 # ---------------------------------------------------------------------------
 # Spec plumbing
 # ---------------------------------------------------------------------------
@@ -168,7 +172,8 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
             stash /= 16
     mem_floor = (mem["argument_size_in_bytes"]
                  + mem["output_size_in_bytes"] + 2.0 * stash)
-    terms = C.roofline_terms(corrected, n_chips, mem_floor_bytes=mem_floor)
+    terms = C.roofline_terms(corrected, n_chips, mem_floor_bytes=mem_floor,
+                             device_kind=TARGET_DEVICE_KIND)
     model_flops = _model_flops_per_device(cfg, shape, n_chips)
     # TPU-peak estimate: true-dtype args + half of the f32-inflated temps
     peak_tpu_est = (mem["argument_size_in_bytes"]

@@ -5,11 +5,8 @@ importing this module never touches jax device state; the dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
 import and only then builds the mesh.
 
-``compat_make_mesh`` papers over the ``jax.sharding.AxisType`` API churn:
-newer jax versions want explicit axis types (and deprecate the implicit
-default), older versions (<= 0.4.x) don't expose ``AxisType`` at all and
-``jax.make_mesh`` rejects the ``axis_types`` kwarg.  All mesh construction
-in this repo (and the tests) goes through this helper.
+``compat_make_mesh`` builds every mesh in this repo (and the tests) with
+Auto axis types (``jax.make_mesh`` defaults to Explicit ones).
 """
 from __future__ import annotations
 
@@ -20,33 +17,11 @@ import numpy as np
 
 
 def compat_make_mesh(shape, axis_names, devices=None):
-    """Version-compatible ``jax.make_mesh`` with Auto axis types when the
-    running jax supports them.
-
-    ``devices``: optional explicit device list (``prod(shape)`` of them) —
-    the heterogeneous-group path: ``jax.make_mesh`` insists on covering
-    ALL local devices, but a per-server :class:`DeviceGroup` mesh spans a
-    SUBSET, so those are built directly over the given slice (in the given
-    order, keeping device partitions disjoint and deterministic)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if devices is not None:
-        arr = np.asarray(list(devices), dtype=object).reshape(tuple(shape))
-        if axis_type is not None:
-            try:
-                return jax.sharding.Mesh(
-                    arr, tuple(axis_names),
-                    axis_types=(axis_type.Auto,) * len(axis_names))
-            except TypeError:
-                pass
-        return jax.sharding.Mesh(arr, tuple(axis_names))
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                tuple(shape), tuple(axis_names),
-                axis_types=(axis_type.Auto,) * len(axis_names))
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(tuple(shape), tuple(axis_names))
+    """``jax.make_mesh`` with Auto axis types, over ``devices`` (the first
+    ``prod(shape)`` of them; default all local devices) when given."""
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         (jax.sharding.AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def group_meshes(group_shapes: Dict, axis_names=("data", "model"),

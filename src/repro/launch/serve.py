@@ -10,6 +10,7 @@ import jax
 
 from repro.configs import get_reduced_config
 from repro.core import GB, LLMSpec, Problem, ServerSpec, Workload
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import init_params
 from repro.serving import GeoServingSystem, generate
 
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--servers", type=int, default=4)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_reduced_config(args.arch)
     params, _ = init_params(jax.random.PRNGKey(0), cfg)
     llm = LLMSpec(cfg.name, cfg.n_layers, block_bytes=50.0,

@@ -36,7 +36,7 @@ from repro.kernels import (
     flash_attention,
     flash_attention_unsupported,
 )
-from repro.kernels.runtime import NO_WINDOW
+from repro.kernels.runtime import NO_WINDOW, use_kernel
 from repro.models.layers import (
     ParamBuilder,
     ShardingCtx,
@@ -171,21 +171,22 @@ def _use_pallas_flash(backend: str, *, causal=True, window=None, slopes=None,
     """Dispatch predicate for full-sequence attention: the Pallas flash
     kernel serves the call iff the backend asks for it AND the kernel's own
     guard accepts the feature set (otherwise the XLA path is the
-    fallback — same numbers, no silent mishandling)."""
-    return (backend == "pallas"
-            and flash_attention_unsupported(
-                causal=causal, window=window, slopes=slopes,
-                q_start=q_start) is None)
+    fallback — same numbers, no silent mishandling; each fallback is
+    counted in ``kernels.runtime.XLA_FALLBACKS``)."""
+    return use_kernel(backend, "flash_attention",
+                      flash_attention_unsupported(
+                          causal=causal, window=window, slopes=slopes,
+                          q_start=q_start))
 
 
 def _use_pallas_decode(backend: str, *, causal=True, window=None,
                        slopes=None, kv_len=None, scale=None) -> bool:
     """Dispatch predicate for single-token decode attention (see
     :func:`_use_pallas_flash`)."""
-    return (backend == "pallas"
-            and decode_attention_unsupported(
-                causal=causal, window=window, slopes=slopes, kv_len=kv_len,
-                scale=scale) is None)
+    return use_kernel(backend, "decode_attention",
+                      decode_attention_unsupported(
+                          causal=causal, window=window, slopes=slopes,
+                          kv_len=kv_len, scale=scale))
 
 
 def decode_attention_xla(q, ck, cv, pos, window=None, slopes=None,

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.models.layers import ParamBuilder, ShardingCtx
 
@@ -218,11 +217,11 @@ def _apply_moe_ep(params, cfg: ModelConfig, sh: ShardingCtx, x):
         return out, aux
 
     x_spec = P(bt[0] if len(bt) == 1 else tuple(bt), "model", None)
-    out, aux = compat.shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, None), P(ep_axes, None, None),
                   P(ep_axes, None, None), P(ep_axes, None, None)),
-        out_specs=(x_spec, P()),
+        out_specs=(x_spec, P()), check_vma=False,
     )(x, params["router"], params["wg"], params["wu"], params["wo"])
     out = _shared_expert(params, cfg, sh, x, out)
     return sh.act(out, "batch", "seq_act", None), aux

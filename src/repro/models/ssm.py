@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.kernels.ssd import ssd as _ssd_kernel
+from repro.kernels.runtime import use_kernel
 from repro.kernels.ssd import ssd_unsupported
 from repro.kernels.wkv6 import wkv6 as _wkv6_kernel
 from repro.kernels.wkv6 import wkv6_unsupported
@@ -142,7 +143,7 @@ def apply_mamba_full(params, cfg: ModelConfig, sh: ShardingCtx, x,
     A = -jnp.exp(params["A_log"])  # (h,) negative
 
     xh = xc.reshape(B, S, h, p).astype(jnp.float32)
-    if backend == "pallas" and ssd_unsupported() is None:
+    if use_kernel(backend, "ssd", ssd_unsupported()):
         y, ssm_state = _ssd_kernel(xh, Bm, Cm, dtv, A, params["D"])
         y = y.reshape(B, S, di).astype(x.dtype)
         y = sh.act(y, "batch", "seq", "inner_act")
@@ -303,7 +304,7 @@ def apply_rwkv_tm_full(params, cfg: ModelConfig, sh: ShardingCtx, x,
     g = jax.nn.silu((xg @ params["wg"].astype(x.dtype)).astype(jnp.float32))
     lw = _rwkv_decay(params, xw).reshape(B, S, h, hd)  # log decay per channel
 
-    if backend == "pallas" and wkv6_unsupported() is None:
+    if use_kernel(backend, "wkv6", wkv6_unsupported()):
         y, wkv_state = _wkv6_kernel(
             r.astype(jnp.float32), k.astype(jnp.float32),
             v.astype(jnp.float32), lw, params["u"])

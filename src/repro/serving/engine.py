@@ -228,6 +228,13 @@ class BlockServer:
         self.group = group
         self.mesh = mesh = group.mesh
         self.n_chips = group.n_chips
+        # the device this server computes on (its group's first; the solo
+        # twin runs on the default device)
+        self.device = (group.devices or jax.devices())[0]
+        # where hop inputs must live: a round buffer arrives committed to
+        # the previous hop server's devices, which may be other chips
+        self._inputs_at = (None if mesh is None
+                           else NamedSharding(mesh, P()))
         if mesh is not None:
             from repro.launch.sharding import (
                 block_param_shardings, pool_tree_shardings, thaw_rules)
@@ -291,6 +298,13 @@ class BlockServer:
         return self.pool.n_sessions()
 
     # -- compute ------------------------------------------------------------
+    def _local(self, *xs):
+        """Hop inputs moved onto this server's devices (a no-op for the solo
+        twin and for inputs already there)."""
+        if self._inputs_at is None:
+            return xs
+        return jax.device_put(xs, self._inputs_at)
+
     def _layer_params(self, l_rel: int):
         for r, (kind, lo, hi) in enumerate(self.runs):
             if lo <= l_rel < hi:
@@ -334,10 +348,10 @@ class BlockServer:
         chunk's state into the pool.  ``phase`` selects encoder vs
         non-encoder runs for enc-dec stacks (see make_pool_prefill_step)."""
         assert self.alive, f"server {self.sid} is dead"
-        args = (h_rows,
-                self._dummy if emb0_rows is None else emb0_rows,
-                self._dummy if enc_rows is None else enc_rows,
-                layer_active, self.layer_ids, offset, phase)
+        args = self._local(h_rows,
+                           self._dummy if emb0_rows is None else emb0_rows,
+                           self._dummy if enc_rows is None else enc_rows,
+                           layer_active) + (self.layer_ids, offset, phase)
         if self.cache_layout == "paged":
             h_out, self.pool.tree = self._prefill_pool(
                 self.run_params, self.shared, self.pool.tree,
@@ -355,10 +369,11 @@ class BlockServer:
         the stale input tree is rebound here and must never be read again
         — see docs/serving.md "Round anatomy" for the aliasing contract."""
         assert self.alive, f"server {self.sid} is dead"
-        args = (h_rows, pos_rows,
-                self._dummy if emb0_rows is None else emb0_rows,
-                self._zero_encl if enc_len_rows is None else enc_len_rows,
-                layer_active, self.layer_ids)
+        args = self._local(
+            h_rows, pos_rows,
+            self._dummy if emb0_rows is None else emb0_rows,
+            self._zero_encl if enc_len_rows is None else enc_len_rows,
+            layer_active) + (self.layer_ids,)
         if self.cache_layout == "paged":
             h_out, self.pool.tree = self._step(
                 self.run_params, self.shared, self.pool.tree,
@@ -374,10 +389,11 @@ class BlockServer:
         the round buffers, decode them through the pooled step, scatter the
         results back — ONE dispatch, donated pool, no host transfer."""
         assert self.alive, f"server {self.sid} is dead"
-        args = (h_round, pos_round,
-                self._dummy if emb0_round is None else emb0_round,
-                encl_round, slot_of_row, row_of_slot, layer_active,
-                self.layer_ids)
+        args = self._local(
+            h_round, pos_round,
+            self._dummy if emb0_round is None else emb0_round,
+            encl_round, slot_of_row, row_of_slot,
+            layer_active) + (self.layer_ids,)
         if self.cache_layout == "paged":
             h_round, self.pool.tree = self._round_step(
                 self.run_params, self.shared, self.pool.tree,
@@ -626,6 +642,7 @@ class GeoServingSystem:
         else:
             self.R = R
             self.placement = petals_bp(problem)
+        self._client_device = jax.devices()[0]
         self.servers: Dict[int, BlockServer] = {}
         self._build_servers()
         self.sessions: Dict[int, EngineSession] = {}
@@ -714,7 +731,8 @@ class GeoServingSystem:
     # ------------------------------------------------------------------
     # τ calibration from the (sharded) pooled step
     # ------------------------------------------------------------------
-    def calibrate_taus(self) -> Dict[int, float]:
+    def calibrate_taus(self, device_kind: Optional[str] = None
+                       ) -> Dict[int, float]:
         """Per-server τ (per-block per-token decode seconds, eq. (1))
         derived from each server's ACTUAL pooled decode step: AOT
         lowering + compile, ``launch.costs`` roofline over the per-device
@@ -723,17 +741,24 @@ class GeoServingSystem:
         a heterogeneous deployment (solo next to 2-device TP next to
         4-device EP) yields a genuinely non-constant τ vector — that
         heterogeneity flows straight into the perf model the placement
-        (MILP/CG-BP), eq. (20) routing, and the simulator consume."""
+        (MILP/CG-BP), eq. (20) routing, and the simulator consume.
+
+        ``device_kind``: the chip whose peaks price the steps; defaults to
+        the kind of the device each server runs on.  A kind without
+        published peaks (the CPU) raises, so a CPU caller names the chip it
+        models."""
         from repro.launch import costs as C
 
         taus = {}
         for j, srv in self.servers.items():
+            kind = device_kind or srv.device.device_kind
             cost = srv.decode_step_cost()
             taus[j] = C.tau_from_step_cost(cost, srv.n_chips, srv.m,
-                                           srv.pool.n_rows)
+                                           srv.pool.n_rows, device_kind=kind)
         return taus
 
-    def calibrated_problem(self) -> Problem:
+    def calibrated_problem(self, device_kind: Optional[str] = None
+                           ) -> Problem:
         """A copy of ``self.problem`` whose server τs come from
         :meth:`calibrate_taus` — feed it back into placement / the
         simulator.  The live engine's virtual clock keeps the original
@@ -741,7 +766,8 @@ class GeoServingSystem:
         parity contract."""
         from repro.core.perf_model import with_server_taus
 
-        return with_server_taus(self.problem, self.calibrate_taus())
+        return with_server_taus(self.problem,
+                                self.calibrate_taus(device_kind))
 
     # ------------------------------------------------------------------
     # Session lifecycle (continuous batching API)
@@ -1155,12 +1181,17 @@ class GeoServingSystem:
         sess.state = "active"
         sess.end = (sess.start + sess.prefill_time
                     + max(sess.n_new - 1, 0) * sess.per_token_time)
-        logits = self._lm_head(self.params["embed"], h_last)
+        logits = self._lm_head(self.params["embed"], self._at_client(h_last))
         sess.last_logits = logits[0, 0]
         sess.tokens.append(self._sample_tokens([sess])[0])
         sess.n_generated = 1
         sess._h = None
         sess._emb0 = None
+
+    def _at_client(self, h):
+        """The last hop's hidden states, brought back to the client (the
+        default device, which holds the embedding) for the lm_head."""
+        return jax.device_put(h, self._client_device)
 
     def _sample_tokens(self, sessions: List[EngineSession]) -> List[int]:
         """One vmapped sampler call for a round's sessions: per-row
@@ -1576,7 +1607,8 @@ class GeoServingSystem:
                 seeds[g] = s.sampling.seed
                 tindex[g] = s.n_generated
             toks_dev, logits_rows = self._round_tail(
-                self.params["embed"], h_round, jnp.asarray(temps),
+                self.params["embed"], self._at_client(h_round),
+                jnp.asarray(temps),
                 jnp.asarray(topks), jnp.asarray(seeds),
                 jnp.asarray(tindex))
             self.round_stats["tail_dispatches"] += 1

@@ -8,8 +8,8 @@ mixed-position pooled rows (co-resident sessions with different prompt
 lengths), windowed gemma3, ALiBi bloom, MLA deepseek decode, rwkv and
 hybrid (zamba2) recurrent pools, enc-dec (seamless) cross-attention with
 mixed encoder lengths, and chunked prefill (q_start).  The CI
-``kernel-parity`` job runs this file with ``REPRO_PALLAS_INTERPRET=1`` so
-kernel changes cannot land without oracle parity.
+``kernel-parity`` job runs this file (interpret mode, off-TPU) so kernel
+changes cannot land without oracle parity.
 """
 import jax
 import jax.numpy as jnp

@@ -1,6 +1,7 @@
 """Unit tests for the dry-run cost extraction (HLO collective parsing +
 ring-model wire bytes + roofline terms)."""
 import numpy as np
+import pytest
 
 from repro.launch.costs import (CostSummary, parse_collectives,
                                 roofline_terms)
@@ -34,15 +35,19 @@ def test_parse_collectives_ring_model():
 def test_roofline_terms_dominance():
     c = CostSummary(flops=197e12, bytes_accessed=819e9 / 2,
                     coll_wire_bytes=50e9 / 4)
-    t = roofline_terms(c, 256)
+    t = roofline_terms(c, 256, device_kind="TPU v5 lite")
     assert abs(t["compute_s"] - 1.0) < 1e-9
     assert abs(t["memory_s"] - 0.5) < 1e-9
     assert abs(t["collective_s"] - 0.25) < 1e-9
     assert t["dominant"] == "compute"
     assert t["compute_fraction_of_bound"] == 1.0
     # the tpu estimate is half the HLO figure, floored by the analytic floor
-    t2 = roofline_terms(c, 256, mem_floor_bytes=819e9)
+    t2 = roofline_terms(c, 256, mem_floor_bytes=819e9,
+                        device_kind="TPU v5 lite")
     assert abs(t2["memory_s_tpu_est"] - 1.0) < 1e-9
+    # a kind without published peaks is an error, not a v5e default
+    with pytest.raises(ValueError, match="cpu"):
+        roofline_terms(c, 256, device_kind="cpu")
 
 
 def test_scaled_add():

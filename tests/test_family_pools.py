@@ -189,11 +189,14 @@ def test_engine_matches_monolithic(arch):
         batch["frames"] = jnp.asarray(frames)[None]
     ref_logits, caches = prefill(params, cfg, NULL_SH, batch,
                                  cache_len=len(toks) + n_new + 4)
-    # logits agree to float-eps (engine and monolithic are different jitted
-    # programs; XLA fusion jitters the last bits), tokens exactly
+    # logits agree to float32 rounding (engine and monolithic are different
+    # jitted programs; XLA fusion reorders reductions), tokens exactly.  The
+    # absolute term covers near-zero logits, where rtol says nothing: up to
+    # ~1.4e-5 was seen on the CPU backend for zamba2's shared-attention
+    # stack, against the O(1) differences a wrong cache or mask gives.
     np.testing.assert_allclose(np.asarray(logits[0]),
                                np.asarray(ref_logits[0]), rtol=2e-4,
-                               atol=1e-5)
+                               atol=5e-5)
     seq = [int(jnp.argmax(ref_logits[0]))]
     pos = len(toks)
     for _ in range(n_new - 1):
@@ -202,7 +205,7 @@ def test_engine_matches_monolithic(arch):
         lg = system.decode(sid, seq[-1])
         np.testing.assert_allclose(np.asarray(lg[0]),
                                    np.asarray(lg_ref[0]), rtol=2e-4,
-                                   atol=1e-5)
+                                   atol=5e-5)
         seq.append(int(jnp.argmax(lg_ref[0])))
         pos += 1
     assert seq == ref

@@ -528,17 +528,38 @@ def test_rwkv_tm_backends_agree():
 
 
 def test_runtime_interpret_env_override(monkeypatch):
+    """Interpret mode follows the backend alone: no environment variable
+    can force the interpreter on a TPU."""
+    from repro.kernels import runtime
     from repro.kernels.runtime import default_interpret, resolve_backend
 
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert default_interpret() is True
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert default_interpret() is False
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "false")
-    assert default_interpret() is False
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
     assert default_interpret() is (jax.default_backend() != "tpu")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(runtime.jax, "default_backend", lambda: "tpu")
+    assert default_interpret() is False
+    monkeypatch.setattr(runtime.jax, "default_backend", lambda: "cpu")
+    assert default_interpret() is True
     assert resolve_backend("xla") == "xla"
     assert resolve_backend("pallas") == "pallas"
     with pytest.raises(ValueError, match="pallas"):
         resolve_backend("cuda")
+
+
+def test_pallas_fallbacks_are_counted(monkeypatch):
+    """A ``backend="pallas"`` call that a kernel's predicate sends to XLA is
+    counted by kernel and reason; XLA-backend calls are never counted."""
+    import collections
+
+    from repro.kernels import runtime
+    from repro.models.attention import _use_pallas_decode, _use_pallas_flash
+
+    monkeypatch.setattr(runtime, "XLA_FALLBACKS", collections.Counter())
+    assert _use_pallas_flash("pallas") is True
+    assert _use_pallas_flash("xla", causal=False, window=8) is False
+    assert not runtime.XLA_FALLBACKS
+    assert _use_pallas_flash("pallas", causal=False, window=8) is False
+    assert _use_pallas_decode("pallas", causal=False, window=8) is False
+    assert dict(runtime.XLA_FALLBACKS) == {
+        "flash_attention: sliding-window masking on non-causal attention": 1,
+        "decode_attention: sliding-window masking on non-causal (cross) "
+        "decode attention": 1}

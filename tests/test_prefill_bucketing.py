@@ -1,5 +1,6 @@
-"""Batched prefill with prompt-length bucketing: bit-exact parity of the
-bucketed/chunked pooled path against the legacy serial prefill, chunk
+"""Batched prefill with prompt-length bucketing: parity of the
+bucketed/chunked pooled path against the legacy serial prefill (logits to
+float32 rounding across programs, bit-exact solo vs grouped), chunk
 interleaving with decode rounds, and group deferral under the slot budget."""
 import jax
 import jax.numpy as jnp
@@ -13,6 +14,16 @@ from repro.models import NULL_SH, decode_step, init_params, prefill
 from repro.serving import (ContinuousBatchingScheduler, GeoServingSystem,
                            bucket_for, default_prefill_buckets)
 from repro.sim.workload import bursty_requests, prompts_for_lengths
+
+
+# The serial exact-length prefill and the padded-bucket pooled prefill are
+# different XLA programs: the compiler tiles and orders their float32
+# reductions differently (an S-row vs a bucket-row matmul), so their logits
+# agree to float32 rounding (up to ~7e-6 absolute on O(1) logits on the CPU
+# backend), not bit for bit.  A masking or cache-offset bug moves logits by
+# orders of magnitude more.  Solo vs grouped runs of ONE program stay
+# bit-exact below.
+PROGRAM_TOL = dict(atol=5e-5, rtol=1e-4)
 
 
 def _build(arch="llama3_2_1b", n_servers=4, R=2, mem=900.0, max_sessions=8,
@@ -92,7 +103,8 @@ def test_default_buckets_and_lookup():
 
 def test_single_session_bucket_bitexact():
     """A group of ONE padded session (prompt 5 -> bucket 8) must match the
-    legacy serial (exact-length) prefill bit-for-bit."""
+    legacy serial (exact-length) prefill: the same tokens, and logits within
+    ``PROGRAM_TOL`` (two different programs)."""
     rng = np.random.RandomState(0)
     prompts = [rng.randint(2, 64, 5)]
     _, _, _, sys_serial = _build(prefill_mode="serial", l_in=5)
@@ -101,13 +113,14 @@ def test_single_session_bucket_bitexact():
     toks_b, logits_b = _run_group(sys_batched, prompts, 6, coalesce=True)
     assert toks_s == toks_b
     for a, b in zip(logits_s[0], logits_b[0]):
-        np.testing.assert_array_equal(a, b)
+        np.testing.assert_allclose(a, b, **PROGRAM_TOL)
 
 
 @pytest.mark.parametrize("arch", ["llama3_2_1b", "rwkv6_7b"])
 def test_mixed_length_group_parity(arch):
     """Mixed-length co-admitted sessions (two buckets for decoder; exact-
-    length groups for rwkv) must reproduce the serial path bit-for-bit."""
+    length groups for rwkv) must reproduce the serial path: the same tokens,
+    and logits within ``PROGRAM_TOL``."""
     rng = np.random.RandomState(1)
     lengths = [3, 5, 5, 9, 12]
     prompts = [rng.randint(2, 64, n) for n in lengths]
@@ -120,7 +133,7 @@ def test_mixed_length_group_parity(arch):
     for ls, lb in zip(logits_s, logits_b):
         assert len(ls) == len(lb) == n_new
         for a, b in zip(ls, lb):
-            np.testing.assert_array_equal(a, b)
+            np.testing.assert_allclose(a, b, **PROGRAM_TOL)
     # and the serial reference itself equals the monolithic stack
     for p, got in zip(prompts, toks_s):
         assert got[len(p):] == _monolithic_ref(cfg, params, p, n_new)

@@ -191,7 +191,7 @@ def test_calibrated_problem_gets_fresh_route_cache():
     prob = Problem(llm, servers, 1, rtt, rtt * 3, workload=Workload(4, 4))
     system = GeoServingSystem(cfg, params, prob, R=2, max_new_tokens=4,
                               max_sessions=4)
-    cal = system.calibrated_problem()
+    cal = system.calibrated_problem("TPU v5 lite")
     assert not np.array_equal(cal.tau(), prob.tau())
 
     # fresh controller on the calibrated problem: memo belongs to cal

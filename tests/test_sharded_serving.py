@@ -178,10 +178,10 @@ def test_calibrated_taus_feed_perf_model():
     cfg, system = _build("llama3_2_1b", mesh)
     cost = next(iter(system.servers.values())).decode_step_cost()
     assert cost.flops > 0 and cost.bytes_accessed > 0
-    taus = system.calibrate_taus()
+    taus = system.calibrate_taus("TPU v5 lite")
     assert set(taus) == set(system.servers)
     assert all(np.isfinite(t) and t > 0 for t in taus.values())
-    cal = system.calibrated_problem()
+    cal = system.calibrated_problem("TPU v5 lite")
     np.testing.assert_allclose(cal.tau(),
                                [taus[s.sid] for s in cal.servers])
     # the live problem is untouched
@@ -192,8 +192,12 @@ def test_calibration_without_mesh():
     """mesh=None servers calibrate too (n_chips=1): the same entry point
     covers plain single-device serving."""
     cfg, system = _build("llama3_2_1b", None)
-    taus = system.calibrate_taus()
+    taus = system.calibrate_taus("TPU v5 lite")
     assert all(np.isfinite(t) and t > 0 for t in taus.values())
+    # priced by the server's own device unless a kind is named: the CPU
+    # has no published peaks, so it raises instead of borrowing a TPU's
+    with pytest.raises(ValueError, match="cpu"):
+        system.calibrate_taus()
 
 
 # ---------------------------------------------------------------------------
@@ -261,7 +265,7 @@ def test_sharded_step_params_and_pools_actually_shard():
 
     assert any_sharded(srv.run_params), "no param leaf is partitioned"
     assert any_sharded(srv.pool.tree), "no cache leaf is partitioned"
-    taus = system.calibrate_taus()
+    taus = system.calibrate_taus("TPU v5 lite")
     assert all(np.isfinite(t) and t > 0 for t in taus.values())
 
 
@@ -392,13 +396,13 @@ def test_hetero_calibrated_taus_are_non_constant():
 
     groups = group_meshes(HETERO_SHAPES)
     cfg, system = _build_hetero("llama3_2_1b", groups)
-    taus = system.calibrate_taus()
+    taus = system.calibrate_taus("TPU v5 lite")
     assert set(taus) == {0, 1, 2}
     assert all(np.isfinite(t) and t > 0 for t in taus.values())
     assert len({round(t, 15) for t in taus.values()}) > 1, taus
     # more devices -> per-device step cost can only shrink
     assert taus[2] <= taus[0] * (1 + 1e-9), taus
-    cal = system.calibrated_problem()
+    cal = system.calibrated_problem("TPU v5 lite")
     np.testing.assert_allclose(cal.tau(), [taus[0], taus[1], taus[2]])
     assert system.problem.tau().tolist() == [0.01, 0.02, 0.03]
 

@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.configs import get_reduced_config
 from repro.data import make_batches
 from repro.launch.mesh import compat_make_mesh
@@ -73,8 +72,8 @@ def test_int8_allreduce_accuracy():
     rng = np.random.RandomState(0)
     x = jnp.asarray(rng.randn(n, 64, 8), jnp.float32)
 
-    f = compat.shard_map(lambda v: int8_allreduce(v[0], "x"), mesh=mesh,
-                         in_specs=P("x"), out_specs=P())
+    f = jax.shard_map(lambda v: int8_allreduce(v[0], "x"), mesh=mesh,
+                      in_specs=P("x"), out_specs=P(), check_vma=False)
     got = f(x)
     want = np.sum(np.asarray(x), axis=0)
     rel = np.abs(np.asarray(got) - want) / (np.abs(want) + 1e-3)
