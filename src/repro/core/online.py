@@ -17,6 +17,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.core.bounds import cg_upper_bound
 from repro.core.perf_model import (Placement, Problem, Route,
                                    route_per_token_time, route_prefill_time,
@@ -120,9 +121,10 @@ class OnlineBPRR:
     def admit(self, client: int, now: float
               ) -> Tuple[Optional[Route], float, float, int]:
         """Route a new request.  Returns (route, start_time, end_time, sid)."""
-        states = self.server_state_arrays(now)
-        route, cost, wait = ws_rr(self.problem, self.placement, client,
-                                  states, cache=self._route_cache)
+        with tracing.span("route", client=client):
+            states = self.server_state_arrays(now)
+            route, cost, wait = ws_rr(self.problem, self.placement, client,
+                                      states, cache=self._route_cache)
         if route is None:
             return None, np.inf, np.inf, -1
         start = now + wait

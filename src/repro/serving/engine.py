@@ -59,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.core.perf_model import Placement, Problem, Route
 from repro.core.placement import petals_bp
@@ -862,6 +863,13 @@ class GeoServingSystem:
         :meth:`drain_prefill`.  In ``prefill_mode="serial"`` the legacy
         one-session-at-a-time prefill runs immediately instead.
         """
+        with tracing.span("admit", offered=len(sids)) as sp:
+            got = self._admit_sessions(sids, now)
+            if sp:
+                sp.set(granted=len(got), sids=got)
+            return got
+
+    def _admit_sessions(self, sids: List[int], now: float) -> List[int]:
         admitted: List[EngineSession] = []
         failed_clients: set = set()
         for sid in sids:
@@ -926,11 +934,12 @@ class GeoServingSystem:
         block resident sessions."""
         done: List[int] = []
         still: List[_PrefillGroup] = []
-        for g in self._prefill_groups:
-            done.extend(self._prefill_group_round(g))
-            if any(s.state == "prefilling" and s.prompt_len > g.offset
-                   for s in g.members):
-                still.append(g)
+        with tracing.span("prefill_round", groups=len(self._prefill_groups)):
+            for g in self._prefill_groups:
+                done.extend(self._prefill_group_round(g))
+                if any(s.state == "prefilling" and s.prompt_len > g.offset
+                       for s in g.members):
+                    still.append(g)
         self._prefill_groups = still
         return done
 
@@ -1047,38 +1056,21 @@ class GeoServingSystem:
             lo, hi = max(e, self._n_enc), e + k
             if lo < hi:  # hop hosts decode-phase blocks
                 N = srv.pool.n_rows
-                d = active[0]._h.shape[-1]
-                dt = np.asarray(active[0]._h).dtype
-                h_buf = np.zeros((N, t_pad, d), dt)
-                emb0_buf = (np.zeros((N, t_pad, d), dt)
-                            if self._needs_emb0 else None)
-                enc_buf = None
-                if self._is_enc_dec:
-                    enc_buf = np.zeros(
-                        (N, g.enc_len, d),
-                        np.asarray(active[0].enc_out).dtype)
-                mask = np.zeros((srv.m, N), bool)
-                for s in active:
-                    row = srv.pool.rows[s.sid]
-                    # client-side failover cache: the UNPADDED chunk
-                    # entering this hop (stitched to the full prompt at
-                    # completion)
-                    g.hop_chunks[s.sid][hop].append(s._h[:, : spans[s.sid]])
-                    h_buf[row] = np.asarray(s._h[0])
-                    if emb0_buf is not None:
-                        emb0_buf[row] = np.asarray(s._emb0[0])
-                    if enc_buf is not None:
-                        enc_buf[row] = np.asarray(s.enc_out[0])
-                    mask[lo - srv.a: hi - srv.a, row] = True
-                h_out = srv.prefill_rows(
-                    jnp.asarray(h_buf), jnp.asarray(mask), offset=g.offset,
-                    phase=phase,
-                    emb0_rows=(None if emb0_buf is None
-                               else jnp.asarray(emb0_buf)),
-                    enc_rows=(None if enc_buf is None
-                              else jnp.asarray(enc_buf)))
-                for s in active:
-                    s._h = h_out[srv.pool.rows[s.sid]][None]
+                with tracing.span("prefill.hop") as sp:
+                    if sp:
+                        sp.set(server=j, rows=N, t_pad=t_pad, m=srv.m,
+                               tok_layers_useful=(hi - lo) * sum(
+                                   spans.values()),
+                               tok_layers_computed=N * t_pad * srv.m)
+                    with tracing.span("prefill.stage"):
+                        h_rows, mask, emb0_rows, enc_rows = \
+                            self._prefill_hop_inputs(g, hop, active, spans,
+                                                     srv, lo, hi, t_pad)
+                    h_out = srv.prefill_rows(
+                        h_rows, mask, offset=g.offset, phase=phase,
+                        emb0_rows=emb0_rows, enc_rows=enc_rows)
+                    for s in active:
+                        s._h = h_out[srv.pool.rows[s.sid]][None]
             # Virtual clock, consistent with eq. (1): the group's chunk
             # travels the hop as ONE message — its members share a single
             # RTT — and each session is charged its own (weighted) k·τ^I of
@@ -1122,6 +1114,41 @@ class GeoServingSystem:
                                                spans[s.sid]])
                 done.append(s.sid)
         return done
+
+    def _prefill_hop_inputs(self, g: _PrefillGroup, hop: int,
+                            active: List[EngineSession],
+                            spans: Dict[int, int], srv: BlockServer,
+                            lo: int, hi: int, t_pad: int):
+        """The device inputs of one prefill hop: every member's hidden rows
+        (and emb0 / encoder rows) staged into (N, t_pad, d) host buffers at
+        its pool row, uploaded with the layer mask as
+        (h_rows, layer_active, emb0_rows, enc_rows).  Also records each
+        member's unpadded chunk in its client-side failover cache."""
+        N = srv.pool.n_rows
+        d = active[0]._h.shape[-1]
+        dt = np.asarray(active[0]._h).dtype
+        h_buf = np.zeros((N, t_pad, d), dt)
+        emb0_buf = (np.zeros((N, t_pad, d), dt)
+                    if self._needs_emb0 else None)
+        enc_buf = None
+        if self._is_enc_dec:
+            enc_buf = np.zeros((N, g.enc_len, d),
+                               np.asarray(active[0].enc_out).dtype)
+        mask = np.zeros((srv.m, N), bool)
+        for s in active:
+            row = srv.pool.rows[s.sid]
+            # client-side failover cache: the UNPADDED chunk entering this
+            # hop (stitched to the full prompt at completion)
+            g.hop_chunks[s.sid][hop].append(s._h[:, : spans[s.sid]])
+            h_buf[row] = np.asarray(s._h[0])
+            if emb0_buf is not None:
+                emb0_buf[row] = np.asarray(s._emb0[0])
+            if enc_buf is not None:
+                enc_buf[row] = np.asarray(s.enc_out[0])
+            mask[lo - srv.a: hi - srv.a, row] = True
+        return (jnp.asarray(h_buf), jnp.asarray(mask),
+                None if emb0_buf is None else jnp.asarray(emb0_buf),
+                None if enc_buf is None else jnp.asarray(enc_buf))
 
     def _prefill_serial(self, sess: EngineSession):
         """Legacy one-session-per-call prefill — the exact-length reference
@@ -1181,9 +1208,11 @@ class GeoServingSystem:
         sess.state = "active"
         sess.end = (sess.start + sess.prefill_time
                     + max(sess.n_new - 1, 0) * sess.per_token_time)
-        logits = self._lm_head(self.params["embed"], self._at_client(h_last))
-        sess.last_logits = logits[0, 0]
-        sess.tokens.append(self._sample_tokens([sess])[0])
+        with tracing.span("prefill.finalize", sid=sess.sid):
+            logits = self._lm_head(self.params["embed"],
+                                   self._at_client(h_last))
+            sess.last_logits = logits[0, 0]
+            sess.tokens.append(self._sample_tokens([sess])[0])
         sess.n_generated = 1
         sess._h = None
         sess._emb0 = None
@@ -1296,6 +1325,26 @@ class GeoServingSystem:
         layout additionally grows every decoding session's pages to cover
         the write position, preempting victims under page pressure (see
         ``preempt_session``)."""
+        with tracing.span("decode_round") as sp:
+            with tracing.span("round.prepare"):
+                group = self._round_group(sids)
+                fused = bool(group) and self.decode_mode != "serial"
+                if fused:
+                    inputs = self._round_inputs(group)
+            if sp:
+                sp.set(sessions=len(group), W=self._round_width)
+            if not group:
+                return {}
+            if not fused:
+                return self._decode_round_serial(group)
+            return self._decode_round_fused(group, *inputs)
+
+    def _round_group(self, sids: Optional[List[int]]
+                     ) -> List[EngineSession]:
+        """The sessions a round decodes (``sids`` as in
+        :meth:`decode_round`), once due faults have fired, preempted
+        sessions that fit again have resumed and, paged, every member's
+        pages cover its write position."""
         explicit = sids is not None
         if self.fault_plan is not None:
             # virtual-clock fault injection: events due by the round's
@@ -1332,11 +1381,7 @@ class GeoServingSystem:
                 # capacity-starved for good) — fail it with a reason so
                 # the caller's drive loop terminates
                 self._abort_stuck_head()
-        if not group:
-            return {}
-        if self.decode_mode == "serial":
-            return self._decode_round_serial(group)
-        return self._decode_round_fused(group)
+        return group
 
     # ------------------------------------------------------------------
     # Paged layout: page growth, preemption, resume
@@ -1570,12 +1615,11 @@ class GeoServingSystem:
                 out[sess.sid] = nxt
         return out
 
-    def _decode_round_fused(self, group: List[EngineSession]
-                            ) -> Dict[int, int]:
-        """Device-resident round over fixed-width (W, ...) buffers: the
-        hidden states never leave the device between the embed and the
-        round tail, and the ONLY host sync is the final batched token
-        readback (one ``np.asarray``)."""
+    def _round_inputs(self, group: List[EngineSession]):
+        """A device-resident round's inputs over fixed-width (W, ...)
+        buffers: each session's slot, and on the device the batched embed
+        of every slot's last token, its cache position and its encoder
+        length."""
         if len(group) > self._round_width:  # rare: re-trace at the new W
             self._round_width = len(group)
         W = self._round_width
@@ -1589,30 +1633,41 @@ class GeoServingSystem:
             encl_buf[i] = s.enc_len
         h_round = self._embed(self.params["embed"], jnp.asarray(tok_buf))
         self.round_stats["embed_dispatches"] += 1
+        return slot, h_round, jnp.asarray(pos_buf), jnp.asarray(encl_buf)
+
+    def _decode_round_fused(self, group: List[EngineSession],
+                            slot: Dict[int, int], h_round, pos_round,
+                            encl_round) -> Dict[int, int]:
+        """Device-resident round over the fixed-width buffers of
+        :meth:`_round_inputs`: the hidden states never leave the device
+        between the embed and the round tail, and the ONLY host sync is
+        the final batched token readback (one ``np.asarray``)."""
+        W = self._round_width
         emb0_round = h_round if self._needs_emb0 else None
-        h_round = self._traverse_fused(group, slot, h_round,
-                                       jnp.asarray(pos_buf), emb0_round,
-                                       jnp.asarray(encl_buf))
+        h_round = self._traverse_fused(group, slot, h_round, pos_round,
+                                       emb0_round, encl_round)
         emit = [s for s in group if s.state == "active"]
         out: Dict[int, int] = {}
         if emit:
-            temps = np.zeros((W,), np.float32)
-            topks = np.zeros((W,), np.int32)
-            # uint32: the full SamplingSpec.seed range (validated there)
-            seeds = np.zeros((W,), np.uint32)
-            tindex = np.zeros((W,), np.int32)
-            for s in emit:
-                g = slot[s.sid]
-                temps[g], topks[g] = s.sampling.row_params()
-                seeds[g] = s.sampling.seed
-                tindex[g] = s.n_generated
-            toks_dev, logits_rows = self._round_tail(
-                self.params["embed"], self._at_client(h_round),
-                jnp.asarray(temps),
-                jnp.asarray(topks), jnp.asarray(seeds),
-                jnp.asarray(tindex))
-            self.round_stats["tail_dispatches"] += 1
-            toks = np.asarray(toks_dev)  # THE one host sync of the round
+            with tracing.span("round.tail"):
+                temps = np.zeros((W,), np.float32)
+                topks = np.zeros((W,), np.int32)
+                # uint32: the full SamplingSpec.seed range (validated there)
+                seeds = np.zeros((W,), np.uint32)
+                tindex = np.zeros((W,), np.int32)
+                for s in emit:
+                    g = slot[s.sid]
+                    temps[g], topks[g] = s.sampling.row_params()
+                    seeds[g] = s.sampling.seed
+                    tindex[g] = s.n_generated
+                toks_dev, logits_rows = self._round_tail(
+                    self.params["embed"], self._at_client(h_round),
+                    jnp.asarray(temps),
+                    jnp.asarray(topks), jnp.asarray(seeds),
+                    jnp.asarray(tindex))
+                self.round_stats["tail_dispatches"] += 1
+            with tracing.span("round.sync"):
+                toks = np.asarray(toks_dev)  # THE one host sync of the round
             for s in emit:
                 g = slot[s.sid]
                 s.pos += 1
@@ -1629,6 +1684,12 @@ class GeoServingSystem:
         e_lo = sum(sess.route.blocks[:hop])
         return e_lo, e_lo + sess.route.blocks[hop]
 
+    def _hop_layers(self, sess: EngineSession, hop: int) -> Tuple[int, int]:
+        """The blocks [lo, hi) of the hop that do decode-time work (its
+        encoder blocks do none); empty where the hop holds only those."""
+        e_lo, e_hi = self._hop_span(sess, hop)
+        return max(e_lo, self._n_enc), e_hi
+
     def _traverse_core(self, group: List[EngineSession], process_group):
         """THE decode traversal skeleton shared by the host-staged and
         device-resident paths: advance every session in ``group`` through
@@ -1643,8 +1704,8 @@ class GeoServingSystem:
         def skip_enc_hops(s):
             while (s.state == "active"
                    and progress[s.sid] < len(s.route.servers)):
-                e_lo, e_hi = self._hop_span(s, progress[s.sid])
-                if max(e_lo, self._n_enc) < e_hi:
+                lo, hi = self._hop_layers(s, progress[s.sid])
+                if lo < hi:
                     return
                 progress[s.sid] += 1
 
@@ -1702,7 +1763,16 @@ class GeoServingSystem:
                 groups.setdefault(s.route.servers[progress[s.sid]],
                                   []).append(s)
             for j, members in groups.items():
-                process_group(self.servers[j], members, progress)
+                srv = self.servers[j]
+                with tracing.span("round.hop") as sp:
+                    if sp:  # attrs set here: nothing is built when off
+                        sp.set(server=j, rows=srv.pool.n_rows, m=srv.m,
+                               row_layers_useful=sum(
+                                   hi - lo for lo, hi in (
+                                       self._hop_layers(s, progress[s.sid])
+                                       for s in members)),
+                               row_layers_computed=srv.pool.n_rows * srv.m)
+                    process_group(srv, members, progress)
                 for s in members:
                     progress[s.sid] += 1
 
@@ -1728,8 +1798,7 @@ class GeoServingSystem:
             for s in members:
                 hop = progress[s.sid]
                 row = srv.pool.rows[s.sid]
-                e_lo, e_hi = self._hop_span(s, hop)
-                lo = max(e_lo, self._n_enc)
+                lo, hi = self._hop_layers(s, hop)
                 s.hop_inputs[hop].append(s._h)
                 h_buf[row] = np.asarray(s._h[0])
                 pos_buf[row] = s.pos
@@ -1737,7 +1806,7 @@ class GeoServingSystem:
                     emb0_buf[row] = np.asarray(s._emb0[0])
                 if encl_buf is not None:
                     encl_buf[row] = s.enc_len
-                mask[lo - srv.a: e_hi - srv.a, row] = True
+                mask[lo - srv.a: hi - srv.a, row] = True
                 rows[s.sid] = row
             h_out = srv.decode_rows(
                 jnp.asarray(h_buf), jnp.asarray(pos_buf),
@@ -1768,13 +1837,11 @@ class GeoServingSystem:
             mask = np.zeros((srv.m, N), bool)
             gidx = []
             for s in members:
-                hop = progress[s.sid]
                 row = srv.pool.rows[s.sid]
-                e_lo, e_hi = self._hop_span(s, hop)
-                lo = max(e_lo, self._n_enc)
+                lo, hi = self._hop_layers(s, progress[s.sid])
                 slot_of_row[row] = slot[s.sid]
                 row_of_slot[slot[s.sid]] = row
-                mask[lo - srv.a: e_hi - srv.a, row] = True
+                mask[lo - srv.a: hi - srv.a, row] = True
                 gidx.append(slot[s.sid])
             # client-side failover cache: ONE device gather of the hop's
             # member rows; each member holds a lazy (buffer, index) record
@@ -1853,20 +1920,21 @@ class GeoServingSystem:
     def retire_session(self, sid: int) -> Optional[EngineSession]:
         """Free the session's rows/block-slots on every server; returns the
         session record (metrics live on it)."""
-        sess = self.sessions.pop(sid, None)
-        if sess is None:
-            return None
-        if sess.state == "prefilling":  # dropped mid-prompt: leave its group
-            for g in self._prefill_groups:
-                g.members = [s for s in g.members if s.sid != sid]
-            self._prefill_groups = [g for g in self._prefill_groups
-                                    if g.members]
-        if sess.state != "failed":
-            sess.state = "done"
-        for j in set(sess.route.servers):
-            if j in self.servers:
-                self.servers[j].evict(sid)
-        return sess
+        with tracing.span("retire", sid=sid):
+            sess = self.sessions.pop(sid, None)
+            if sess is None:
+                return None
+            if sess.state == "prefilling":  # dropped mid-prompt
+                for g in self._prefill_groups:
+                    g.members = [s for s in g.members if s.sid != sid]
+                self._prefill_groups = [g for g in self._prefill_groups
+                                        if g.members]
+            if sess.state != "failed":
+                sess.state = "done"
+            for j in set(sess.route.servers):
+                if j in self.servers:
+                    self.servers[j].evict(sid)
+            return sess
 
     def concurrency(self) -> int:
         """Sessions currently holding cache slots (prefilling or decoding)."""

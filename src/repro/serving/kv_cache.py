@@ -1041,8 +1041,8 @@ def _decode_step_body(cfg: ModelConfig, kinds: Tuple[str, ...],
     :func:`make_pool_decode_step` (row-buffer entry point) and
     :func:`make_pool_round_step` (the fused round-resident entry point).
 
-    step(run_params, shared_params, pool_trees, h, pos, emb0, enc_len,
-         layer_active, layer_ids) -> (h, pool_trees)
+    decode_step(run_params, shared_params, pool_trees, h, pos, emb0,
+                enc_len, layer_active, layer_ids) -> (h, pool_trees)
 
     * ``run_params`` / ``shared_params`` / ``pool_trees``: as in
       :func:`make_pool_prefill_step`,
@@ -1077,8 +1077,8 @@ def _decode_step_body(cfg: ModelConfig, kinds: Tuple[str, ...],
 
         ep_sh = ShardingCtx(mesh, thaw_rules(rules))
 
-    def step(run_params, shared_params, pool_trees, h, pos, emb0, enc_len,
-             layer_active, layer_ids):
+    def decode_step(run_params, shared_params, pool_trees, h, pos, emb0,
+                    enc_len, layer_active, layer_ids):
         new_trees = list(pool_trees)
         for r, (kind, lo, hi) in enumerate(runs):
             if kind == "enc":  # stateless: no decode-time work
@@ -1186,7 +1186,7 @@ def _decode_step_body(cfg: ModelConfig, kinds: Tuple[str, ...],
             new_trees[r] = new_tree
         return h, tuple(new_trees)
 
-    return step
+    return decode_step
 
 
 @functools.lru_cache(maxsize=None)
@@ -1214,8 +1214,8 @@ def make_pool_decode_step(cfg: ModelConfig, kinds: Tuple[str, ...],
     body = _decode_step_body(cfg, kinds, backend, mesh, rules)
     pools, rows, repl = _mesh_constraints(mesh, rules)
 
-    def step(run_params, shared_params, pool_trees, h, pos, emb0, enc_len,
-             layer_active, layer_ids):
+    def decode_step(run_params, shared_params, pool_trees, h, pos, emb0,
+                    enc_len, layer_active, layer_ids):
         pool_trees = pools(pool_trees)
         h = rows(h, "batch", None, None)
         pos = rows(pos, "batch")
@@ -1227,7 +1227,7 @@ def make_pool_decode_step(cfg: ModelConfig, kinds: Tuple[str, ...],
                             emb0, enc_len, layer_active, layer_ids)
         return rows(h, "batch", None, None), pools(new_trees)
 
-    return jax.jit(step, donate_argnums=(2,))
+    return jax.jit(decode_step, donate_argnums=(2,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -1387,8 +1387,8 @@ def make_paged_decode_step(cfg: ModelConfig, kinds: Tuple[str, ...],
     runs = kind_runs(kinds)
     cons = None if mesh is None else _mesh_constraints(mesh, rules)
 
-    def step(run_params, shared_params, pool_trees, page_table, h, pos,
-             emb0, enc_len, layer_active, layer_ids):
+    def decode_step(run_params, shared_params, pool_trees, page_table, h,
+                    pos, emb0, enc_len, layer_active, layer_ids):
         if cons is not None:
             pools, rows, repl = cons
             pool_trees, page_table = pools(pool_trees), repl(page_table)
@@ -1404,7 +1404,7 @@ def make_paged_decode_step(cfg: ModelConfig, kinds: Tuple[str, ...],
             new_trees = cons[0](new_trees)
         return h_out, new_trees
 
-    return jax.jit(step, donate_argnums=(2,))
+    return jax.jit(decode_step, donate_argnums=(2,))
 
 
 @functools.lru_cache(maxsize=None)
