@@ -193,11 +193,11 @@ def decode_attention_xla(q, ck, cv, pos, window=None, slopes=None,
                          causal=True, kv_len=None):
     """Single-step attention over a cache without KV-head expansion.
 
-    q (B,1,H,D); ck (B,T,Kv,D); cv (B,T,Kv,Dv); pos: current position scalar.
-    ``kv_len``: optional (traced) count of valid cache positions — masks
-    ``kv_pos >= kv_len``.  Needed by non-causal (cross) attention when the
-    cache is allocated longer than the valid prefix; causal attention is
-    already masked by ``pos``.
+    q (B,1,H,D); ck (B,T,Kv,D); cv (B,T,Kv,Dv); pos: current position,
+    scalar or (B,) per row.  ``kv_len``: optional (traced) count of valid
+    cache positions, scalar or (B,) — masks ``kv_pos >= kv_len``.  Needed
+    by non-causal (cross) attention when the cache is allocated longer than
+    the valid prefix; causal attention is already masked by ``pos``.
     """
     B, _, H, D = q.shape
     T, Kv = ck.shape[1], ck.shape[2]
@@ -208,14 +208,17 @@ def decode_attention_xla(q, ck, cv, pos, window=None, slopes=None,
     logits = jnp.einsum("bkgd,btkd->bkgt", qg, ck,
                         preferred_element_type=jnp.float32) * scale
     kv_pos = jnp.arange(T)
-    diff = pos - kv_pos
-    ok = ((diff >= 0) & (diff < window)) if causal else jnp.ones((T,), bool)
+    diff = jnp.asarray(pos)[..., None] - kv_pos  # (T,) or (B, T)
+    if causal:
+        ok = (diff >= 0) & (diff < window)
+    else:
+        ok = jnp.ones(diff.shape, bool)
     if kv_len is not None:
-        ok = ok & (kv_pos < kv_len)
+        ok = ok & (kv_pos < jnp.asarray(kv_len)[..., None])
     if slopes is not None:
         logits = logits + (slopes.reshape(Kv, G)[None, :, :, None]
-                           * (-jnp.abs(diff))[None, None, None, :])
-    logits = jnp.where(ok[None, None, None, :], logits, _NEG_INF)
+                           * (-jnp.abs(diff))[..., None, None, :])
+    logits = jnp.where(ok[..., None, None, :], logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgt,btkd->bkgd", probs.astype(cv.dtype), cv)
     return out.reshape(B, 1, H, cv.shape[-1])
@@ -336,6 +339,44 @@ def apply_gqa_full(params, cfg: ModelConfig, sh: ShardingCtx, x, positions,
     return y, kv_out
 
 
+def gqa_decode_entry(params, cfg: ModelConfig, x, pos):
+    """The new token's query and cache entry for single-token decode.
+
+    x (B,1,d); ``pos`` scalar or (B,) per row.  Returns (q, {"k", "v"}):
+    q (B,1,H,hd) and the un-expanded (B,1,Kv,hd) K/V to cache at ``pos``,
+    both RoPE'd (and qk-normed) as the config says."""
+    q = _q_proj(params, cfg, x)
+    k, v = _kv_proj(params, cfg, x)
+    if cfg.qk_norm:
+        q = rms_norm_simple(q, params["q_norm"], cfg.norm_eps)
+        k = rms_norm_simple(k, params["k_norm"], cfg.norm_eps)
+    if cfg.pos_kind == "rope":
+        cos, sin = rope_angles(jnp.asarray(pos)[..., None], cfg.head_dim,
+                               cfg.rope_theta)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+    return q, {"k": k, "v": v}
+
+
+def gqa_decode_attend(params, cfg: ModelConfig, q, cache_k, cache_v, pos,
+                      window=None, cross: bool = False, kv_len=None,
+                      backend: str = "xla"):
+    """Attention of the decode query over a cache (already holding the new
+    token for self-attention), then the output projection: (B,1,d).
+    ``pos`` / ``kv_len``: scalar or (B,) per row."""
+    slopes = alibi_slopes(cfg.n_heads) if cfg.pos_kind == "alibi" else None
+    win = None if cross else window  # non-causal ignores the window
+    if _use_pallas_decode(backend, causal=not cross, window=win,
+                          slopes=slopes, kv_len=kv_len):
+        out = decode_attention(q, cache_k, cache_v, pos, window=win,
+                               slopes=slopes, causal=not cross,
+                               kv_len=kv_len)
+    else:
+        out = decode_attention_xla(q, cache_k, cache_v, pos, window, slopes,
+                                   causal=not cross, kv_len=kv_len)
+    return jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(q.dtype))
+
+
 def apply_gqa_decode(params, cfg: ModelConfig, sh: ShardingCtx, x, cache_k,
                      cache_v, pos, window=None, cross: bool = False,
                      kv_len=None, backend: str = "xla"):
@@ -349,35 +390,18 @@ def apply_gqa_decode(params, cfg: ModelConfig, sh: ShardingCtx, x, cache_k,
     "xla" (oracle) or "pallas" (decode kernel when the feature set is
     supported, XLA fallback otherwise).
     """
-    q = _q_proj(params, cfg, x)
     if not cross:
-        k, v = _kv_proj(params, cfg, x)
+        q, new = gqa_decode_entry(params, cfg, x, pos)
+        cache_k = jax.lax.dynamic_update_slice_in_dim(
+            cache_k, new["k"].astype(cache_k.dtype), pos, axis=1)
+        cache_v = jax.lax.dynamic_update_slice_in_dim(
+            cache_v, new["v"].astype(cache_v.dtype), pos, axis=1)
+    else:
+        q = _q_proj(params, cfg, x)
         if cfg.qk_norm:
             q = rms_norm_simple(q, params["q_norm"], cfg.norm_eps)
-            k = rms_norm_simple(k, params["k_norm"], cfg.norm_eps)
-        if cfg.pos_kind == "rope":
-            posv = jnp.asarray(pos)[None]
-            cos, sin = rope_angles(posv, cfg.head_dim, cfg.rope_theta)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-        cache_k = jax.lax.dynamic_update_slice_in_dim(
-            cache_k, k.astype(cache_k.dtype), pos, axis=1)
-        cache_v = jax.lax.dynamic_update_slice_in_dim(
-            cache_v, v.astype(cache_v.dtype), pos, axis=1)
-    elif cfg.qk_norm:
-        q = rms_norm_simple(q, params["q_norm"], cfg.norm_eps)
-
-    slopes = alibi_slopes(cfg.n_heads) if cfg.pos_kind == "alibi" else None
-    win = None if cross else window  # non-causal ignores the window
-    if _use_pallas_decode(backend, causal=not cross, window=win,
-                          slopes=slopes, kv_len=kv_len):
-        out = decode_attention(q, cache_k, cache_v, pos, window=win,
-                               slopes=slopes, causal=not cross,
-                               kv_len=kv_len)
-    else:
-        out = decode_attention_xla(q, cache_k, cache_v, pos, window, slopes,
-                                   causal=not cross, kv_len=kv_len)
-    y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
+    y = gqa_decode_attend(params, cfg, q, cache_k, cache_v, pos, window,
+                          cross=cross, kv_len=kv_len, backend=backend)
     return y, cache_k, cache_v
 
 
@@ -472,26 +496,30 @@ def apply_mla_full(params, cfg: ModelConfig, sh: ShardingCtx, x, positions,
     return y, kv_out
 
 
-def apply_mla_decode(params, cfg: ModelConfig, sh: ShardingCtx, x,
-                     cache_latent, cache_krope, pos, backend: str = "xla"):
-    """Absorbed-form MLA decode: attend in latent space (MQA with kv_head=1).
+def mla_decode_entry(params, cfg: ModelConfig, x, pos):
+    """The new token's query and cache entry for absorbed MLA decode.
 
-    cache_latent (B,T,lora), cache_krope (B,T,rope).  Writes the new token's
-    latent/k_rope at ``pos`` and attends.  Returns (y, cache_latent,
-    cache_krope).  ``backend``: "xla" (the oracle pre-scales q to undo the
-    helper's 1/sqrt(lora+rope)) or "pallas" (the kernel takes the faithful
-    1/sqrt(nope+rope) scale directly).
-    """
+    x (B,1,d); ``pos`` scalar or (B,) per row.  Returns ((q_nope, q_rope),
+    {"latent", "krope"}) with the (B,1,lora) / (B,1,rope) entry to cache
+    at ``pos``."""
+    posv = jnp.asarray(pos)[..., None]
+    q = _mla_q(params, cfg, x, posv)
+    latent, krope = mla_latent(params, cfg, x, posv)
+    return q, {"latent": latent, "krope": krope}
+
+
+def mla_decode_attend(params, cfg: ModelConfig, q, cache_latent, cache_krope,
+                      pos, backend: str = "xla"):
+    """Absorbed-form attention in latent space (MQA with kv_head=1) over a
+    cache already holding the new token, then W_uv and the output
+    projection: (B,1,d).  ``backend``: "xla" (the oracle pre-scales q to
+    undo the helper's 1/sqrt(lora+rope)) or "pallas" (the kernel takes the
+    faithful 1/sqrt(nope+rope) scale directly)."""
     nope, rope = cfg.head_dim, cfg.rope_head_dim
-    posv = jnp.asarray(pos)[None]
-    q_nope, q_rope = _mla_q(params, cfg, x, posv)
-    new_latent, new_krope = mla_latent(params, cfg, x, posv)
-    cache_latent = jax.lax.dynamic_update_slice_in_dim(
-        cache_latent, new_latent.astype(cache_latent.dtype), pos, axis=1)
-    cache_krope = jax.lax.dynamic_update_slice_in_dim(
-        cache_krope, new_krope.astype(cache_krope.dtype), pos, axis=1)
+    q_nope, q_rope = q
+    dt = q_nope.dtype
     # absorb W_uk into the query:  q_lat[h] = q_nope[h] @ W_uk[:, h, :]^T
-    q_lat = jnp.einsum("bshk,lhk->bshl", q_nope, params["wuk"].astype(x.dtype))
+    q_lat = jnp.einsum("bshk,lhk->bshl", q_nope, params["wuk"].astype(dt))
     q_eff = jnp.concatenate([q_lat, q_rope], axis=-1)  # (B,1,H,lora+rope)
     keys = jnp.concatenate([cache_latent, cache_krope], axis=-1)[:, :, None, :]
     # the faithful softmax scale is 1/sqrt(nope+rope), not the
@@ -507,6 +535,5 @@ def apply_mla_decode(params, cfg: ModelConfig, sh: ShardingCtx, x,
         ctx = decode_attention_xla(q_eff * scale_fix, keys,
                                    cache_latent[:, :, None, :], pos)
     # ctx (B,1,H,lora): apply W_uv per head then the output projection.
-    v_heads = jnp.einsum("bshl,lhk->bshk", ctx, params["wuv"].astype(x.dtype))
-    y = jnp.einsum("bshk,hkd->bsd", v_heads, params["wo"].astype(x.dtype))
-    return y, cache_latent, cache_krope
+    v_heads = jnp.einsum("bshl,lhk->bshk", ctx, params["wuv"].astype(dt))
+    return jnp.einsum("bshk,hkd->bsd", v_heads, params["wo"].astype(dt))

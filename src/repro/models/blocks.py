@@ -141,27 +141,47 @@ def decoder_block_full(params, cfg: ModelConfig, sh: ShardingCtx, h, positions,
     return h, cache, aux
 
 
+def decoder_decode_entry(params, cfg: ModelConfig, h, pos):
+    """ln1 and the new token's projections of one decode step.  h (B,1,d);
+    ``pos`` scalar or (B,) per row.  Returns (query, entry): ``entry`` holds
+    the block's cache leaves (``k``/``v``, or ``latent``/``krope`` for MLA)
+    for the one new token, each (B,1,...), to be written at ``pos``."""
+    x = apply_norm(params["ln1"], cfg, h)
+    if cfg.attn_kind == "mla":
+        return attn.mla_decode_entry(params["attn"], cfg, x, pos)
+    return attn.gqa_decode_entry(params["attn"], cfg, x, pos)
+
+
+def decoder_decode_attend(params, cfg: ModelConfig, h, query, cache, pos,
+                          layer_idx=0, backend: str = "xla"):
+    """The rest of the attention half: ``query`` attends over ``cache``
+    (which already holds the new token at ``pos``) -> residual (+ sandwich
+    post-norm).  Caches are (B,T,...); ``pos`` scalar or (B,)."""
+    if cfg.attn_kind == "mla":
+        a = attn.mla_decode_attend(params["attn"], cfg, query,
+                                   cache["latent"], cache["krope"], pos,
+                                   backend=backend)
+    else:
+        a = attn.gqa_decode_attend(params["attn"], cfg, query, cache["k"],
+                                   cache["v"], pos,
+                                   window_for_layer(cfg, layer_idx),
+                                   backend=backend)
+    if cfg.sandwich_norm:
+        a = apply_norm(params["post_ln1"], cfg, a)
+    return h + a
+
+
 def decoder_block_attn_decode(params, cfg: ModelConfig, sh: ShardingCtx, h,
                               cache, pos, layer_idx=0, backend: str = "xla"):
     """Attention half of :func:`decoder_block_decode`: ln1 -> attention ->
-    residual (+ sandwich post-norm).  Returns (h, cache) with the FFN half
-    still to run — the pooled decode step uses the split to batch the MoE
-    FFN over its rows (the pure-EP all-to-all path)."""
-    win = window_for_layer(cfg, layer_idx)
-    x = apply_norm(params["ln1"], cfg, h)
-    if cfg.attn_kind == "mla":
-        a, lat, kr = attn.apply_mla_decode(
-            params["attn"], cfg, sh, x, cache["latent"], cache["krope"], pos,
-            backend=backend)
-        cache = {"latent": lat, "krope": kr}
-    else:
-        a, ck, cv = attn.apply_gqa_decode(
-            params["attn"], cfg, sh, x, cache["k"], cache["v"], pos, win,
-            backend=backend)
-        cache = {"k": ck, "v": cv}
-    if cfg.sandwich_norm:
-        a = apply_norm(params["post_ln1"], cfg, a)
-    return h + a, cache
+    residual (+ sandwich post-norm), writing the new token into ``cache``
+    at ``pos``.  Returns (h, cache) with the FFN half still to run."""
+    query, new = decoder_decode_entry(params, cfg, h, pos)
+    cache = {key: jax.lax.dynamic_update_slice_in_dim(
+        cache[key], new[key].astype(cache[key].dtype), pos, axis=1)
+        for key in new}
+    return decoder_decode_attend(params, cfg, h, query, cache, pos,
+                                 layer_idx, backend=backend), cache
 
 
 def decoder_block_ffn(params, cfg: ModelConfig, sh: ShardingCtx, h):

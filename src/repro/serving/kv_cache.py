@@ -16,7 +16,7 @@ Two granularities:
   dicts.
 * ``CachePool`` — the continuous-batching layout: per server, ONE stacked
   state tree per *run* of same-kind hosted blocks, leaves
-  ``(run_layers, n_rows, ...)``, so a single jitted step (vmapped over rows,
+  ``(run_layers, n_rows, ...)``, so a single jitted step (over all rows,
   scanned over each run) serves every session resident on that server.  The
   pooled step factories take the server's static per-layer kind tuple and
   dispatch each run to its family's block functions — the program still
@@ -705,6 +705,24 @@ def _mask_tree(new, old, active):
         new, old)
 
 
+def _write_token(tree, layer, entry, pos, active):
+    """Write each active row's new token into pooled leaves, in place.
+
+    ``entry`` leaves are (n_rows, 1, ...); row r's lands at
+    ``(layer, r, pos[r])`` of the (layers, n_rows, T, ...) leaf of the same
+    key.  Inactive rows write nothing: their time index falls off the end
+    and the scatter drops it.  ``pos`` clamps into [0, T) as
+    ``dynamic_update_slice`` clamps it."""
+    out = dict(tree)
+    for key, new in entry.items():
+        leaf = tree[key]
+        n_rows, T = leaf.shape[1], leaf.shape[2]
+        t = jnp.where(active, jnp.clip(pos, 0, T - 1), T)
+        out[key] = leaf.at[layer, jnp.arange(n_rows), t].set(
+            new[:, 0].astype(leaf.dtype), mode="drop")
+    return out
+
+
 def _masked_ranged_write(cache, chunk, active, keys, lo, span):
     """Ranged [lo, lo+span) masked write of chunk leaves named ``keys``."""
     out = dict(cache)
@@ -1058,10 +1076,13 @@ def _decode_step_body(cfg: ModelConfig, kinds: Tuple[str, ...],
       sliding-window patterns).
 
     Encoder runs are statically skipped (their StateSpec is not
-    decode-active).  The computation always spans ALL rows with fixed
-    shapes: adding or removing sessions changes only the mask, never the
-    traced program, so per-session results are bit-for-bit identical
-    between a crowded pool and a pool with a single resident session.
+    decode-active).  Decoder runs write each active row's new token into
+    the pool in place; the other kinds compute whole per-row states and
+    keep the old ones on inactive rows.  The computation always spans ALL
+    rows with fixed shapes: adding or removing sessions changes only the
+    mask, never the traced program, so per-session results are bit-for-bit
+    identical between a crowded pool and a pool with a single resident
+    session.
     """
     from repro.kernels.runtime import resolve_backend
     from repro.models import blocks as B
@@ -1077,6 +1098,46 @@ def _decode_step_body(cfg: ModelConfig, kinds: Tuple[str, ...],
 
         ep_sh = ShardingCtx(mesh, thaw_rules(rules))
 
+    def decoder_run(p_stack, tree, h, pos, active, layer_ids):
+        """One run of decoder layers.  The pool rides the scan's carry, so
+        the donated leaves are written in place, one token per active row
+        per layer (:func:`_write_token`), and attention reads each layer
+        where it lies.  The FFN half stays per row (or on the EP grid)."""
+        grid = _ep_row_grid(cfg, mesh, rules, p_stack, h.shape[0])
+        if grid is None:
+            def ffn(p, h2):
+                return jax.vmap(lambda hr: B.decoder_block_ffn(
+                    p, cfg, NULL_SH, hr[None])[0])(h2)
+        else:
+            # Padded-MoE EP route: the position-free FFN half regroups the
+            # rows into a (n_data, rows/n_data) token grid so apply_moe
+            # takes the pure-EP all-to-all inside the pooled step.
+            # _ep_row_grid's no-drop bound makes this emit the per-row
+            # reference mixture exactly (token parity).
+            n_data, rows_per = grid
+
+            def ffn(p, h2):
+                return B.decoder_block_ffn(
+                    p, cfg, ep_sh,
+                    h2.reshape(n_data, rows_per, h2.shape[-1]),
+                ).reshape(h2.shape)
+
+        def body(carry, xs):
+            hc, cache = carry
+            p, i, act, lid = xs
+            query, new = B.decoder_decode_entry(p, cfg, hc, pos)
+            cache = _write_token(cache, i, new, pos, act)
+            h2 = B.decoder_decode_attend(
+                p, cfg, hc, query, {k: cache[k][i] for k in new}, pos, lid,
+                backend=backend)
+            h2 = ffn(p, h2)
+            return (jnp.where(act[:, None, None], h2, hc), cache), None
+
+        (h, tree), _ = jax.lax.scan(
+            body, (h, tree),
+            (p_stack, jnp.arange(active.shape[0]), active, layer_ids))
+        return h, tree
+
     def decode_step(run_params, shared_params, pool_trees, h, pos, emb0,
                     enc_len, layer_active, layer_ids):
         new_trees = list(pool_trees)
@@ -1087,49 +1148,10 @@ def _decode_step_body(cfg: ModelConfig, kinds: Tuple[str, ...],
             act, lids = layer_active[lo:hi], layer_ids[lo:hi]
 
             if kind == "decoder":
-                grid = _ep_row_grid(cfg, mesh, rules, p_stack, h.shape[0])
-
-                if grid is None:
-                    def body(hc, xs):
-                        p, cache, active, lid = xs
-
-                        def one(hr, cr, pr):
-                            hh, cc = B.decoder_block_decode(
-                                p, cfg, NULL_SH, hr[None],
-                                jax.tree.map(lambda x: x[None], cr), pr, lid,
-                                backend=backend)
-                            return hh[0], jax.tree.map(lambda x: x[0], cc)
-
-                        h2, c2 = jax.vmap(one)(hc, cache, pos)
-                        return (jnp.where(active[:, None, None], h2, hc),
-                                _mask_tree(c2, cache, active))
-                else:
-                    # Padded-MoE EP route: attention stays the per-row
-                    # reference trace, the position-free FFN half regroups
-                    # the rows into a (n_data, rows/n_data) token grid so
-                    # apply_moe takes the pure-EP all-to-all inside the
-                    # pooled step.  _ep_row_grid's no-drop bound makes this
-                    # emit the reference mixture exactly (token parity).
-                    n_data, rows_per = grid
-
-                    def body(hc, xs):
-                        p, cache, active, lid = xs
-
-                        def one(hr, cr, pr):
-                            hh, cc = B.decoder_block_attn_decode(
-                                p, cfg, NULL_SH, hr[None],
-                                jax.tree.map(lambda x: x[None], cr), pr, lid,
-                                backend=backend)
-                            return hh[0], jax.tree.map(lambda x: x[0], cc)
-
-                        h2, c2 = jax.vmap(one)(hc, cache, pos)
-                        hf = B.decoder_block_ffn(
-                            p, cfg, ep_sh,
-                            h2.reshape(n_data, rows_per, h2.shape[-1]))
-                        h2 = hf.reshape(h2.shape)
-                        return (jnp.where(active[:, None, None], h2, hc),
-                                _mask_tree(c2, cache, active))
-            elif kind in ("rwkv", "mamba"):
+                h, new_trees[r] = decoder_run(p_stack, tree, h, pos, act,
+                                              lids)
+                continue
+            if kind in ("rwkv", "mamba"):
                 blk = (B.rwkv_block_decode if kind == "rwkv"
                        else B.mamba_block_decode)
 
@@ -1258,7 +1280,7 @@ def make_pool_round_step(cfg: ModelConfig, kinds: Tuple[str, ...],
     Per-slot results are bit-identical to staging the same rows through
     :func:`make_pool_decode_step`: the gather feeds each ACTIVE row exactly
     the values the host path would have scattered in, rows are computed
-    independently (vmap), and inactive rows/slots are `where`-masked.  The
+    independently, and inactive rows/slots are masked.  The
     pool trees (arg 2) are DONATED — same aliasing contract as
     :func:`make_pool_decode_step`.
 

@@ -1,8 +1,9 @@
 """Device-resident decode rounds: per-round dispatch contract (ONE embed,
 ONE fused lm_head+sample tail, one fused gather+step+scatter per
-(hop, server)), donation safety of the pooled cache trees, and
-round-for-round parity of the fused path against the pre-refactor
-``decode_mode="serial"`` reference on decoder / rwkv / hybrid / enc-dec
+(hop, server)), donation safety of the pooled cache trees, the round
+step's one-token writes into its pool, and round-for-round parity of
+the fused path against the pre-refactor ``decode_mode="serial"``
+reference on decoder / rwkv / hybrid / enc-dec
 scenarios — tokens and the virtual clock identical, logits to float-ulp
 (the fused tail's round-width GEMM may order per-row reductions
 differently than the width-1 reference)."""
@@ -15,7 +16,11 @@ from repro.configs import get_reduced_config
 from repro.core import (LLMSpec, Problem, ServerSpec, Workload,
                         shortest_path_route)
 from repro.models import init_params
+from repro.models.blocks import stack_block_kinds
+from repro.models.model import block_param_range
 from repro.serving import GeoServingSystem, SamplingSpec
+from repro.serving.kv_cache import (make_pool_decode_step,
+                                    make_pool_round_step, new_state_pool_tree)
 
 # fused round tail vs per-session reference lm_head: same values up to the
 # GEMM-width reduction order — a few float32 ulps on these scales
@@ -337,3 +342,63 @@ def test_retirement_and_readmission_after_donation():
     assert got == want
     for used, cap in system.slot_usage().values():
         assert used == 0
+
+
+# ---------------------------------------------------------------------------
+# The round step writes one token per active row per layer, nothing else
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("arch", ["llama3_2_1b", "deepseek_v2_236b"])
+def test_round_step_writes_only_the_new_token(arch):
+    """One hop over a pool of random contents, with a row outside the hop
+    and rows active in only one of the server's layers: every pool entry
+    other than (active layer, active row, pos[row]) is bit-identical to
+    before, and the written entries are what the host-staged decode step
+    writes from the same rows (GQA K/V and MLA latent/krope leaves)."""
+    cfg = get_reduced_config(arch)
+    kinds = stack_block_kinds(cfg)
+    L, N, T, W = len(kinds), 4, 16, 3
+    assert L == 2 and set(kinds) == {"decoder"}
+    run_params = (block_param_range(_params_for(cfg), cfg, "decoder", 0, L),)
+    rng = np.random.RandomState(0)
+    template = new_state_pool_tree(cfg, "decoder", L, N, T)
+    before = {k: rng.randn(*x.shape).astype(x.dtype)
+              for k, x in template.items()}
+    h_round = jnp.asarray(rng.randn(W, 1, cfg.d_model), jnp.float32)
+    pos_round = jnp.asarray([5, 0, 15], jnp.int32)
+    slot_of_row = jnp.asarray([2, -1, 0, 1], jnp.int32)
+    row_of_slot = jnp.asarray([2, 3, 0], jnp.int32)
+    active = np.array([[True, False, True, False],   # layer 0
+                       [True, False, False, True]])  # layer 1
+    lids = jnp.arange(L, dtype=jnp.int32)
+    dummy = jnp.zeros((1, 1, 1), jnp.float32)
+    encl_round = jnp.zeros((W,), jnp.int32)
+
+    hop = make_pool_round_step(cfg, kinds)
+    out_round, (after,) = hop(
+        run_params, None, ({k: jnp.asarray(v) for k, v in before.items()},),
+        h_round, pos_round, dummy, encl_round, slot_of_row, row_of_slot,
+        jnp.asarray(active), lids)
+    src = np.clip(np.asarray(slot_of_row), 0, W - 1)
+    pos = np.asarray(pos_round)[src]
+    step = make_pool_decode_step(cfg, kinds)
+    h_staged, (staged,) = step(
+        run_params, None, ({k: jnp.asarray(v) for k, v in before.items()},),
+        h_round[src], jnp.asarray(pos), dummy, encl_round[src],
+        jnp.asarray(active), lids)
+
+    written = np.zeros((L, N, T), bool)
+    for layer in range(L):
+        for row in range(N):
+            written[layer, row, pos[row]] = active[layer, row]
+    assert written.sum() == 4
+    for key, old in before.items():
+        new, ref = np.asarray(after[key]), np.asarray(staged[key])
+        np.testing.assert_array_equal(new[~written], old[~written])
+        np.testing.assert_array_equal(new[written], ref[written])
+        assert not np.any(np.all(new[written] == old[written], axis=-1)), \
+            f"{key}: an active row's token was not written"
+    for slot, row in enumerate(np.asarray(row_of_slot)):
+        np.testing.assert_array_equal(np.asarray(out_round[slot]),
+                                      np.asarray(h_staged[row]))

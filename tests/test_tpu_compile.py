@@ -2,7 +2,8 @@
 the models that use them, and one server's pooled round and prefill steps
 of OLMo-1B at its published widths, compiled for a described (not
 attached) v5e.  Each asserts that Mosaic kernels (``tpu_custom_call``) are
-in the compiled program.  Nothing runs: a pass says the chip's compiler
+in the compiled program; one more asserts that the XLA round step writes
+its donated pool in place.  Nothing runs: a pass says the chip's compiler
 accepts the program, not that its results are right.
 
 The topology is described inside a module fixture, never at import: only
@@ -10,6 +11,7 @@ one process may load the TPU library, and every test worker imports this
 file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -96,15 +98,9 @@ def test_kernel_compiles_for_v5e(one_chip, name, rows):
                                                rows=rows)
 
 
-@pytest.mark.parametrize("step", ["round", "prefill"])
-def test_olmo_pooled_step_compiles_for_v5e(one_chip, monkeypatch, step):
-    """One server's pooled step with ``backend="pallas"``: four OLMo-1B
-    blocks, 8 rows of 1024-token bf16 cache, round width 8, and a
-    512-token prefill bucket."""
-    # the kernels pick interpret mode from the default backend, which is
-    # the CPU here: compile them for the described chip instead
-    for mod in (decode_ops, flash_ops, ssd_ops, wkv6_ops):
-        monkeypatch.setattr(mod, "default_interpret", lambda: False)
+def _olmo_server(one_chip):
+    """Shapes of one OLMo-1B server's pooled-step operands on the chip:
+    (cfg, kinds, params, pool, spec)."""
     cfg = get_config("olmo_1b")
     kinds = ("decoder",) * BLOCKS
     place = lambda tree: jax.tree.map(  # noqa: E731
@@ -117,15 +113,51 @@ def test_olmo_pooled_step_compiles_for_v5e(one_chip, monkeypatch, step):
         cfg, "decoder", BLOCKS, N_ROWS, T),)))
     spec = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
-    d, mask = cfg.d_model, spec((BLOCKS, N_ROWS), jnp.bool_)
+    return cfg, kinds, params, pool, spec
+
+
+def _lower_round(one_chip, backend):
+    cfg, kinds, params, pool, spec = _olmo_server(one_chip)
+    mask = spec((BLOCKS, N_ROWS), jnp.bool_)
     lids, dummy = spec((BLOCKS,), I32), spec((1, 1, 1), F32)
+    return pool, make_pool_round_step(cfg, kinds, backend).lower(
+        params, None, pool, spec((W, 1, cfg.d_model), BF16), spec((W,), I32),
+        dummy, spec((W,), I32), spec((N_ROWS,), I32), spec((W,), I32), mask,
+        lids)
+
+
+@pytest.mark.parametrize("step", ["round", "prefill"])
+def test_olmo_pooled_step_compiles_for_v5e(one_chip, monkeypatch, step):
+    """One server's pooled step with ``backend="pallas"``: four OLMo-1B
+    blocks, 8 rows of 1024-token bf16 cache, round width 8, and a
+    512-token prefill bucket."""
+    # the kernels pick interpret mode from the default backend, which is
+    # the CPU here: compile them for the described chip instead
+    for mod in (decode_ops, flash_ops, ssd_ops, wkv6_ops):
+        monkeypatch.setattr(mod, "default_interpret", lambda: False)
     if step == "round":
-        fn = make_pool_round_step(cfg, kinds, "pallas")
-        lowered = fn.lower(params, None, pool, spec((W, 1, d), BF16),
-                           spec((W,), I32), dummy, spec((W,), I32),
-                           spec((N_ROWS,), I32), spec((W,), I32), mask, lids)
+        _, lowered = _lower_round(one_chip, "pallas")
     else:
+        cfg, kinds, params, pool, spec = _olmo_server(one_chip)
+        mask = spec((BLOCKS, N_ROWS), jnp.bool_)
+        lids, dummy = spec((BLOCKS,), I32), spec((1, 1, 1), F32)
         fn = make_pool_prefill_step(cfg, kinds, "pallas")
-        lowered = fn.lower(params, None, pool, spec((N_ROWS, 512, d), BF16),
-                           dummy, dummy, mask, lids, 0, "all")
+        lowered = fn.lower(params, None, pool,
+                           spec((N_ROWS, 512, cfg.d_model), BF16), dummy,
+                           dummy, mask, lids, 0, "all")
     assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def test_olmo_round_step_writes_pool_in_place(one_chip):
+    """The round step on the XLA backend writes the donated pool in place:
+    its scratch memory is a small fraction of the pool, and no copy of the
+    whole pool is made."""
+    pool, lowered = _lower_round(one_chip, "xla")
+    compiled = lowered.compile()
+    leaves = jax.tree.leaves(pool)
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.1 * pool_bytes, (temp, pool_bytes)
+    shape = ",".join(map(str, leaves[0].shape))
+    assert not re.search(rf"= bf16\[{shape}\]\S* copy\(",
+                         compiled.as_text())
