@@ -1,12 +1,15 @@
 """A configuration file turned into the program's deployment.
 
-``bench/configs/<name>.json`` holds the model's sizes as the program takes
-them (``model``), the servers (``deployment``) and the WAN between the
-client and each server (``wan``).  This module is the only place that
-knows how the program is built from them.
+``bench/configs/<name>.json`` holds the model's architecture and sizes as
+the program takes them (``model``: fields of ``ModelConfig``), the module
+under ``bench/references/`` that makes its weights, computes its reference
+and counts its costs (``reference``), the servers (``deployment``) and the
+WAN between the client and each server (``wan``).  This module is the only
+place that knows how the program is built from them.
 """
 from __future__ import annotations
 
+import dataclasses
 import json
 from pathlib import Path
 from typing import Dict, List, Tuple
@@ -15,10 +18,9 @@ import numpy as np
 
 CONFIG_DIR = Path(__file__).resolve().parent / "configs"
 
-# ModelConfig fields the configuration files may set
-_MODEL_FIELDS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-                 "d_ff", "vocab_size", "pos_kind", "rope_theta", "norm_kind",
-                 "norm_eps", "tie_embeddings", "param_dtype", "act_dtype")
+# keys of a file's ``model`` that only its reference module reads (the
+# program derives them: OLMo's and BLOOM's MLP follow their norm)
+REFERENCE_ONLY = ("mlp",)
 
 
 def load_config(name: str) -> dict:
@@ -26,13 +28,26 @@ def load_config(name: str) -> dict:
 
 
 def model_config(cfg: dict):
-    """The program's ModelConfig for a configuration file."""
+    """The program's ModelConfig for a configuration file: every key of
+    its ``model`` that is a field of ``ModelConfig``, whatever the
+    architecture; ``family`` and ``attn_kind`` are "dense" and "gqa" where
+    the file does not state them.  A key that is neither such a field nor
+    in :data:`REFERENCE_ONLY` raises, naming itself."""
     from repro.configs.base import ModelConfig
 
+    # the file's name and its deployment set the other two fields
+    stated = ({f.name for f in dataclasses.fields(ModelConfig)}
+              - {"name", "max_seq_len"})
     m = cfg["model"]
-    return ModelConfig(name=cfg["name"], family="dense", attn_kind="gqa",
-                       max_seq_len=cfg["deployment"]["max_seq_len"],
-                       **{k: m[k] for k in _MODEL_FIELDS if k in m})
+    unknown = sorted(set(m) - stated - set(REFERENCE_ONLY))
+    if unknown:
+        raise ValueError(f"{cfg['name']}: model keys {unknown} are neither "
+                         f"ModelConfig fields that a file states nor "
+                         f"reference-only keys {list(REFERENCE_ONLY)}")
+    kw = {"family": "dense", "attn_kind": "gqa"}
+    kw.update((k, v) for k, v in m.items() if k in stated)
+    return ModelConfig(name=cfg["name"],
+                       max_seq_len=cfg["deployment"]["max_seq_len"], **kw)
 
 
 def mix_bounds(mix: dict) -> Tuple[int, int]:

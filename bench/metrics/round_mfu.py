@@ -1,8 +1,8 @@
 """The whole decode round's share of the chip's peak: model FLOPs of the
 tokens decoded in the traced stretch's rounds (every block, the attention
 over each token's context, the head) over the rounds' host time times the
-peak bf16 rate of the chips used."""
-from bench.costs import token_model_flops
+peak bf16 rate of the chips used; the FLOPs are counted by
+``token_model_flops`` of the configuration's reference module."""
 
 
 def read(run):
@@ -12,7 +12,7 @@ def read(run):
     rounds = run.rounds()
     if not spans or not rounds:
         return None
-    flops = sum(token_model_flops(run.model, p) for pos in rounds
+    flops = sum(run.arch.token_model_flops(run.model, p) for pos in rounds
                 for p in pos)
     chips = run.deployment.get("chips", 1)
     wall = sum(e - b for b, e in spans)

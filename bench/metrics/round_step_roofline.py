@@ -1,8 +1,8 @@
 """The round step's share of its roofline: for each decode round in the
 traced stretch, the larger of the FLOPs it needs over the chip's peak and
-the bytes it needs over the peak bandwidth (``bench.costs.round_needs``),
-summed, over the device time of the round-step programs."""
-from bench.costs import round_needs
+the bytes it needs over the peak bandwidth (``round_needs`` of the
+configuration's reference module), summed, over the device time of the
+round-step programs."""
 
 
 def read(run):
@@ -14,9 +14,9 @@ def read(run):
     dev = run.trace.module_s("jit_hop")
     if dev <= 0:
         return None
-    dep, pk = run.deployment, run.peaks
+    dep, pk, needs = run.deployment, run.peaks, run.arch.round_needs
     need = 0.0
     for pos in rounds:
-        fl, by = round_needs(run.model, pos, dep["servers"], dep["rows"])
+        fl, by = needs(run.model, pos, dep["servers"], dep["rows"])
         need += max(fl / pk["flops_bf16"], by / pk["hbm_bytes_per_s"])
     return need / dev * 100.0

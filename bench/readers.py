@@ -11,6 +11,7 @@ from __future__ import annotations
 import importlib.util
 from dataclasses import dataclass
 from pathlib import Path
+from types import ModuleType
 from typing import List, Optional, Tuple
 
 from bench.loop import LoopResult
@@ -30,6 +31,9 @@ class RunView:
     deployment: dict
     peaks: Optional[dict]
     trace: Optional[DeviceTrace] = None
+    # the configuration's reference module (``bench/references/``), which
+    # counts its architecture's costs: ``round_needs``, ``token_model_flops``
+    arch: Optional[ModuleType] = None
 
     def spans(self, name: str) -> List[Tuple[float, float]]:
         """(start, end) of the host spans ``name`` that ran in the

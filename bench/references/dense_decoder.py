@@ -1,4 +1,5 @@
-"""Plain reference of a dense decoder-only transformer, and the weights.
+"""Plain reference of a dense decoder-only transformer, its weights and its
+cost count.
 
 The published blocks of OLMo (arXiv:2402.00838: non-parametric LayerNorm,
 RoPE with rotate-half, SwiGLU) and BLOOM (LayerNorm with scale and bias,
@@ -14,11 +15,16 @@ embeddings, and tied embeddings with no scaling.
 the seed, in one jitted call on the device, in the dtype they are served
 in, laid out as the program takes them.  The reference calls it again
 after the program's state is freed, so it takes nothing the program made.
+
+A configuration's reference module is also where the benchmark finds the
+architecture's cost count (:func:`round_needs`, :func:`token_model_flops`)
+and the reference's program for a compile rehearsal
+(:func:`lowered_reference`).
 """
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional
+from typing import Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -240,6 +246,16 @@ def _block(m, h, w, pos):
     return h + qa(a) @ w["wo_mlp"].astype(f32)
 
 
+def _jit_args(m: dict, params: dict, lowp: Optional[str]) -> tuple:
+    """:func:`_logits_jit`'s arguments before the tokens and rows."""
+    flat = flat_leaves(params, m)
+    per_layer = {k: v for k, v in flat.items()
+                 if k not in ("tok", "final_scale", "final_bias")}
+    return (m["norm_kind"], m["norm_eps"], m["pos_kind"],
+            float(m.get("rope_theta", 0.0)), m["mlp"], lowp, flat["tok"],
+            flat.get("final_scale"), flat.get("final_bias"), per_layer)
+
+
 def logits_at(m: dict, params: dict, tokens: np.ndarray,
               rows: np.ndarray, lowp: Optional[str] = None) -> np.ndarray:
     """float32 logits (len(rows), vocab) of the causal pass over ``tokens``
@@ -247,17 +263,25 @@ def logits_at(m: dict, params: dict, tokens: np.ndarray,
     inside the scan, so at most one layer is held in float32.  ``lowp``
     rounds every matrix's input activations to "int8" or "fp8" (the
     control, with :func:`quantized_params`)."""
-    flat = flat_leaves(params, m)
-    per_layer = {k: v for k, v in flat.items()
-                 if k not in ("tok", "final_scale", "final_bias")}
     with jax.default_matmul_precision("highest"):
-        out = _logits_jit(m["norm_kind"], m["norm_eps"], m["pos_kind"],
-                          float(m.get("rope_theta", 0.0)), m["mlp"],
-                          lowp, flat["tok"], flat.get("final_scale"),
-                          flat.get("final_bias"), per_layer,
+        out = _logits_jit(*_jit_args(m, params, lowp),
                           jnp.asarray(tokens, jnp.int32),
                           jnp.asarray(rows, jnp.int32))
     return np.asarray(out, np.float32)
+
+
+def lowered_reference(m: dict, params: dict, seq_len: int, n_rows: int,
+                      sharding):
+    """The program :func:`logits_at` runs over ``seq_len`` tokens read at
+    ``n_rows`` positions, lowered on ``params`` given as shapes
+    (``jax.ShapeDtypeStruct``), with the tokens and rows placed by
+    ``sharding``: for a compile rehearsal, nothing run."""
+    def spec(n):
+        return jax.ShapeDtypeStruct((n,), jnp.int32, sharding=sharding)
+
+    with jax.default_matmul_precision("highest"):
+        return _logits_jit.lower(*_jit_args(m, params, None), spec(seq_len),
+                                 spec(n_rows))
 
 
 def _logits_impl(norm_kind, norm_eps, pos_kind, rope_theta, mlp, lowp, tok,
@@ -279,3 +303,44 @@ def _logits_impl(norm_kind, norm_eps, pos_kind, rope_theta, mlp, lowp, tok,
 
 _logits_jit = jax.jit(_logits_impl, static_argnums=(0, 1, 2, 3, 4, 5))
 
+
+# -- the cost count ----------------------------------------------------------
+#
+# A decode round takes every decoding row one token through every block of
+# its route.  What it needs is the hosted blocks' weights read once per
+# hop, each row's K/V read up to its own position (not to the cache's
+# length), and the hidden rows in and out; it computes two operations per
+# weight per row and the attention over each row's own context.  Model
+# FLOPs of a token add the head's projection over the vocabulary.
+
+def block_params(m: dict) -> int:
+    """Matmul parameters of one block."""
+    d, H, Kv, hd, f = (m["d_model"], m["n_heads"], m["n_kv_heads"],
+                       m["head_dim"], m["d_ff"])
+    attn = d * (H + 2 * Kv) * hd + H * hd * d
+    mlp = (3 if m["mlp"] == "swiglu" else 2) * d * f
+    return attn + mlp
+
+
+def round_needs(m: dict, positions: Sequence[int], n_hops: int,
+                width: int) -> tuple:
+    """(flops, bytes) the round step programs need for one decode round in
+    which rows at ``positions`` cross ``n_hops`` hops holding
+    ``n_layers`` blocks between them, over round buffers ``width`` wide."""
+    L, d, H, Kv, hd = (m["n_layers"], m["d_model"], m["n_heads"],
+                       m["n_kv_heads"], m["head_dim"])
+    bpe = 2  # bf16 weights, cache and activations
+    ctx = sum(p + 1 for p in positions)
+    flops = L * (2 * block_params(m) * len(positions) + 4 * H * hd * ctx)
+    kv = L * 2 * Kv * hd * bpe * ctx
+    weights = L * block_params(m) * bpe
+    hidden = n_hops * 2 * width * d * bpe
+    return flops, weights + kv + hidden
+
+
+def token_model_flops(m: dict, position: int) -> int:
+    """Model FLOPs of one decoded token at ``position``: every block, the
+    attention over its context and the head."""
+    L, H, hd = m["n_layers"], m["n_heads"], m["head_dim"]
+    return (L * (2 * block_params(m) + 4 * H * hd * (position + 1))
+            + 2 * m["d_model"] * m["vocab_size"])

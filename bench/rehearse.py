@@ -29,10 +29,11 @@ def main():
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench import deploy, traffic
-    from bench.references import dense_decoder as ref
+    from bench import check, deploy, traffic
+    from repro.models.blocks import stack_block_kinds
+    from repro.models.model import block_param_range
     from repro.serving.kv_cache import (bucket_for, default_prefill_buckets,
-                                        make_pool_prefill_step,
+                                        kind_runs, make_pool_prefill_step,
                                         make_pool_round_step,
                                         new_state_pool_tree)
 
@@ -42,7 +43,7 @@ def main():
     cfg = deploy.load_config(cell["config"])
     mix = traffic.load_mix(cell["traffic"])
     mc = deploy.model_config(cfg)
-    dep = cfg["deployment"]
+    ref, m, dep = check.reference_module(cfg), cfg["model"], cfg["deployment"]
     k, rows = dep["blocks_per_server"], dep["rows"]
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
@@ -55,13 +56,17 @@ def main():
     def spec(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
 
-    full = jax.eval_shape(lambda: ref.make_params(cfg["model"], 0))
-    blocks = full["segments"]["blocks"]
-    params = place((jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-        (k,) + x.shape[1:], x.dtype), blocks),))
-    pool = place(jax.eval_shape(lambda: (new_state_pool_tree(
-        mc, "decoder", k, rows, dep["max_seq_len"]),)))
-    kinds = ("decoder",) * k
+    # the first server's blocks, in per-kind runs as the engine holds them
+    full = jax.eval_shape(lambda: ref.make_params(m, 0))
+    kinds = stack_block_kinds(mc)[:k]
+    runs = kind_runs(kinds)
+    params = place(tuple(jax.eval_shape(
+        lambda p, kind=kind, lo=lo, hi=hi: block_param_range(
+            p, mc, kind, lo, hi), full) for kind, lo, hi in runs))
+    shared = place(full.get("shared"))
+    pool = place(jax.eval_shape(lambda: tuple(
+        new_state_pool_tree(mc, kind, hi - lo, rows, dep["max_seq_len"])
+        for kind, lo, hi in runs)))
     d = mc.d_model
     mask, lids = spec((k, rows), jnp.bool_), spec((k,), jnp.int32)
     dummy = spec((1, 1, 1), jnp.float32)
@@ -70,25 +75,16 @@ def main():
     i32, act = jnp.int32, jnp.dtype(mc.act_dtype)
     steps = {
         "round": make_pool_round_step(mc, kinds, "xla").lower(
-            params, None, pool, spec((rows, 1, d), act), spec((rows,), i32),
-            dummy, spec((rows,), i32), spec((rows,), i32),
-            spec((rows,), i32), mask, lids),
+            params, shared, pool, spec((rows, 1, d), act),
+            spec((rows,), i32), dummy, spec((rows,), i32),
+            spec((rows,), i32), spec((rows,), i32), mask, lids),
         f"prefill@{bucket}": make_pool_prefill_step(mc, kinds, "xla").lower(
-            params, None, pool, spec((rows, bucket, d), act), dummy, dummy,
-            mask, lids, 0, "all"),
+            params, shared, pool, spec((rows, bucket, d), act), dummy,
+            dummy, mask, lids, 0, "all"),
     }
     l_in, l_out = deploy.mix_bounds(mix)
-    ref_params = place(full)
-    flat = ref.flat_leaves(ref_params, cfg["model"])
-    m = cfg["model"]
-    per_layer = {k_: v for k_, v in flat.items()
-                 if k_ not in ("tok", "final_scale", "final_bias")}
-    with jax.default_matmul_precision("highest"):
-        steps["reference"] = ref._logits_jit.lower(
-            m["norm_kind"], m["norm_eps"], m["pos_kind"],
-            float(m.get("rope_theta", 0.0)), m["mlp"], None, flat["tok"],
-            flat.get("final_scale"), flat.get("final_bias"), per_layer,
-            spec((l_in + l_out,), i32), spec((l_out,), i32))
+    steps["reference"] = ref.lowered_reference(m, place(full), l_in + l_out,
+                                               l_out, chip)
     for name, lowered in steps.items():
         ma = lowered.compile().memory_analysis()
         b = {f: int(getattr(ma, f"{f}_size_in_bytes"))

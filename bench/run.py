@@ -217,7 +217,8 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
             f"second anchor lies {dt.skew_s * 1e3:.3f} ms from where the "
             f"first puts it")
         view = readers.RunView(loop=lr, window=dt.stretch, model=m,
-                               deployment=dep, peaks=pk, trace=dt)
+                               deployment=dep, peaks=pk, trace=dt,
+                               arch=ref)
         for e in cell_metrics(bench, "per_layer", workload):
             v = readers.reader(e["name"])(view)
             if v is not None:

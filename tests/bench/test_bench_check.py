@@ -53,7 +53,7 @@ def test_a_number_without_a_limit_is_read_not_compared():
     assert not check.verdict(None, {"served_gap_max": 0.2})[0]
 
 
-@pytest.mark.parametrize("mix", ["chat", "batch"])
+@pytest.mark.parametrize("mix", ["chat", "batch", "rag"])
 def test_sample_is_drawn_before_the_window(mix):
     m = traffic.load_mix(mix)
     reqs = traffic.make_requests(m, 2 ** 33 + 5, 51.0, 1000)

@@ -1,8 +1,12 @@
 """BENCHMARK.json against the files the harness finds by name."""
+import dataclasses
 import json
 import re
 
+import pytest
+
 from bench import deploy, readers, traffic
+from repro.configs.base import ModelConfig
 from tests.bench.tiny import ROOT
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -30,3 +34,22 @@ def test_every_cell_finds_its_files_and_metrics():
         moved = e2e[e["moves"]]
         assert set(e["workloads"]) <= set(moved.get("workloads", cells))
     assert BENCH["command"] == ["python3", "bench/run.py"]
+
+
+def test_every_model_key_is_a_program_field_or_reference_only():
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    for c in BENCH["configs"]:
+        model = json.loads((ROOT / c["file"]).read_text())["model"]
+        assert set(model) <= fields | set(deploy.REFERENCE_ONLY), c["name"]
+        deploy.model_config(deploy.load_config(c["name"]))
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
+def test_each_cell_reports_a_per_layer_metric_of_each_it_moves(cell):
+    reported = {e["name"] for e in BENCH["end_to_end"]
+                if cell in e.get("workloads", [cell])}
+    per_layer = [e for e in BENCH["per_layer"]
+                 if cell in e.get("workloads", [cell])]
+    assert per_layer and {e["moves"] for e in per_layer} <= reported
+    # every end-to-end metric but the set-up time has a layer to move it
+    assert {e["moves"] for e in per_layer} == reported - {"setup_s"}

@@ -27,7 +27,7 @@ def digest(mix_name, seed, seconds):
     return h.hexdigest()
 
 
-@pytest.mark.parametrize("mix", ["chat", "batch"])
+@pytest.mark.parametrize("mix", ["chat", "batch", "rag"])
 def test_same_seed_same_requests_across_processes(mix):
     code = ("import sys; sys.path.insert(0, sys.argv[1]);"
             "from tests.bench.test_bench_traffic import digest;"
