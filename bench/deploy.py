@@ -54,11 +54,39 @@ def mix_bounds(mix: dict) -> Tuple[int, int]:
     return int(mix["prompt_tokens"]["max"]), int(mix["output_tokens"]["max"])
 
 
+def device_groups(dep: dict):
+    """The program's ``device_groups`` for a deployment: None on one chip
+    (every server on the default device), else one chip per server in
+    server order.  Raises where the servers do not match the chips."""
+    chips, n = int(dep["chips"]), int(dep["servers"])
+    if chips == 1:
+        return None
+    if n != chips:
+        raise ValueError(f"a deployment on {chips} chips puts one server on "
+                         f"each; it states {n} servers")
+    from repro.launch.mesh import group_meshes
+
+    return group_meshes({j: (1, 1) for j in range(n)})
+
+
+def server_devices(system) -> Dict[int, List[int]]:
+    """Server id -> ids of the devices its blocks and cache pool lie on."""
+    import jax
+
+    return {j: sorted({d.id for x in jax.tree.leaves((s.run_params,
+                                                      s.pool.tree))
+                       for d in x.devices()})
+            for j, s in sorted(system.servers.items())}
+
+
 def build(cfg: dict, mix: dict, params):
     """(system, controller): ``GeoServingSystem`` on the program's default
     path (batched prefill, fused decode, slab cache, XLA backend) and the
     ``OnlineBPRR`` controller that routes for it.  Each server holds
-    ``blocks_per_server`` blocks and cache rows for ``rows`` sessions."""
+    ``blocks_per_server`` blocks and cache rows for ``rows`` sessions, on
+    the chip :func:`device_groups` gives it."""
+    import jax
+
     from repro.core import LLMSpec, Problem, ServerSpec, Workload
     from repro.core.online import OnlineBPRR
     from repro.serving import GeoServingSystem
@@ -81,10 +109,12 @@ def build(cfg: dict, mix: dict, params):
     ser = 2 * 8 * float(wan["embed_bytes"]) / float(wan["bandwidth_bps"])
     problem = Problem(llm, servers, 1, prop + ser, prop + ser * l_in,
                       workload=wl)
+    groups = device_groups(dep)
     system = GeoServingSystem(mc, params, problem, algorithm="proposed",
                               R=rows, max_new_tokens=l_out,
                               max_sessions=rows,
-                              max_seq_len=dep["max_seq_len"])
+                              max_seq_len=dep["max_seq_len"],
+                              device_groups=groups)
     ctl = OnlineBPRR(system.problem, R=system.R)
     chain = [(int(a), int(m)) for a, m in zip(system.placement.a,
                                                system.placement.m)]
@@ -93,6 +123,12 @@ def build(cfg: dict, mix: dict, params):
     if sorted(chain) != want or n_rows != [rows] * n:
         raise RuntimeError(f"placement {chain} / rows differ from the "
                            f"deployment {want} x {rows}")
+    given = {j: [(groups[j].devices.flat[0] if groups
+                  else jax.devices()[0]).id] for j in range(n)}
+    homes = server_devices(system)
+    if homes != given:
+        raise RuntimeError(f"servers' blocks and pools lie on devices "
+                           f"{homes}, not on the chips given {given}")
     return system, ctl
 
 

@@ -34,6 +34,8 @@ class RunView:
     # the configuration's reference module (``bench/references/``), which
     # counts its architecture's costs: ``round_needs``, ``token_model_flops``
     arch: Optional[ModuleType] = None
+    # the configuration's ``wan``: the round trips laid on each token
+    wan: Optional[dict] = None
 
     def spans(self, name: str) -> List[Tuple[float, float]]:
         """(start, end) of the host spans ``name`` that ran in the

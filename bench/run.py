@@ -146,6 +146,7 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
     jax.block_until_ready(params)
     requests = traffic.make_requests(mix, seed, seconds, m["vocab_size"])
     system, ctl = deploy.build(cfg, mix, params)
+    log(f"deployment: server -> device ids {deploy.server_devices(system)}")
     warm = deploy.warm_up(system, requests, dep["rows"])
     anchor = jax.jit(bench_anchor)
     one = jax.block_until_ready(anchor(jax.numpy.zeros((), "int32")))
@@ -189,6 +190,9 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
                     watch)
     in_window = clog.between(t_window, t_window + lr.window_closed)
     device = device_block(used)
+    log("memory peak per device (bytes): " + str({
+        d.id: (d.memory_stats() or {}).get("peak_bytes_in_use")
+        for d in used}))
     late = np.asarray(lr.lateness or [0.0])
     log(f"window: {len(lr.records)} requests, {len(lr.rounds)} decode "
         f"rounds, {len(lr.prefills)} prefill rounds; compiles inside the "
@@ -218,7 +222,7 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
             f"first puts it")
         view = readers.RunView(loop=lr, window=dt.stretch, model=m,
                                deployment=dep, peaks=pk, trace=dt,
-                               arch=ref)
+                               arch=ref, wan=cfg["wan"])
         for e in cell_metrics(bench, "per_layer", workload):
             v = readers.reader(e["name"])(view)
             if v is not None:

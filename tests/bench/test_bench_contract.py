@@ -53,3 +53,9 @@ def test_each_cell_reports_a_per_layer_metric_of_each_it_moves(cell):
     assert per_layer and {e["moves"] for e in per_layer} <= reported
     # every end-to-end metric but the set-up time has a layer to move it
     assert {e["moves"] for e in per_layer} == reported - {"setup_s"}
+
+
+@pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
+def test_each_cell_asks_for_the_chips_its_deployment_states(cell):
+    dep = deploy.load_config(cell["config"])["deployment"]
+    assert cell["chips"] == dep["chips"]

@@ -3,7 +3,8 @@ the WAN laid along each request's route."""
 import numpy as np
 import pytest
 
-from bench import stats
+from bench import readers, stats
+from bench.loop import LoopResult
 from bench.stats import Record
 
 WAN = {"rtt_s": [0.010, 0.020, 0.040], "bandwidth_bps": 1e9,
@@ -57,3 +58,36 @@ def test_tokens_per_s_counts_the_window_only():
     assert stats.end_to_end(["tokens_per_s"], recs, WAN, 10.0)[
         "tokens_per_s"][0] == pytest.approx(0.3)
     assert stats.percentile(np.zeros(0), 90) is None
+
+
+def _view(recs, window, wan=WAN):
+    loop = LoopResult(records=recs, spans=[], rounds=[], prefills=[])
+    return readers.RunView(loop=loop, window=window, model={},
+                           deployment={}, peaks=None, wan=wan)
+
+
+def test_itl_per_layer_reads_the_end_to_end_tail_inside_the_stretch():
+    read = readers.reader("itl_p99_ms.chat")
+    recs = [rec(0, 0.0, 0.1 + 0.02 * np.arange(200))]
+    recs += [rec(i, 0.0, [0.3 * i, 0.3 * i + 0.4], route=(1,))
+             for i in range(1, 12)]
+    # a stretch holding every token reads what the window's tail reads
+    assert read(_view(recs, (0.0, 10.0))) == pytest.approx(
+        stats.end_to_end(["itl_p99_ms"], recs, WAN, 10.0)["itl_p99_ms"][0])
+    # a gap counts only where both its tokens lie in the stretch
+    inside = [r for r in recs if r.rid == 0 or 1.0 <= 0.3 * r.rid
+              and 0.3 * r.rid + 0.4 <= 3.0]
+    ts = np.asarray(recs[0].token_times)
+    want = np.r_[np.full(((ts >= 1.0) & (ts <= 3.0)).sum() - 1,
+                         0.02 + stats.wan_delays(WAN, (0, 1, 2), 100)[1]),
+                 np.full(len(inside) - 1,
+                         0.4 + stats.wan_delays(WAN, (1,), 100)[1])]
+    assert read(_view(recs, (1.0, 3.0))) == pytest.approx(
+        np.percentile(want, 99) * 1e3)
+
+
+def test_itl_per_layer_reads_nothing_without_a_gap_in_the_stretch():
+    read = readers.reader("itl_p99_ms.chat")
+    recs = [rec(0, 0.0, [0.5, 1.5]), rec(1, 0.0, [2.0])]
+    assert read(_view(recs, (1.0, 3.0))) is None
+    assert read(_view(recs, (0.0, 3.0), wan=None)) is None
