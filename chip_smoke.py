@@ -240,8 +240,9 @@ def check(tag, got, want, vocab, tol):
 
 
 def lowered_steps(system, bucket):
-    """StableHLO of one server's pooled round step and of its prefill step
-    at ``bucket`` tokens, lowered on the server's live operands."""
+    """StableHLO of one server's pooled round step and of its row-indexed
+    prefill step at ``bucket`` tokens, lowered on the server's live
+    operands."""
     srv = next(iter(system.servers.values()))
     cfg = system.cfg
     N, W = srv.pool.n_rows, system._round_width
@@ -257,10 +258,11 @@ def lowered_steps(system, bucket):
         spec((W, 1, cfg.d_model), act), spec((W,), i32), srv._dummy,
         spec((W,), i32), spec((N,), i32), spec((W,), i32), mask,
         srv.layer_ids).as_text()
-    prefill_txt = srv._prefill_pool.lower(
+    prefill_txt = srv._prefill_row.lower(
         srv.run_params, srv.shared, srv.pool.tree,
-        spec((N, bucket, cfg.d_model), act), srv._dummy, srv._dummy, mask,
-        srv.layer_ids, 0, "all").as_text()
+        spec((1, bucket, cfg.d_model), act), srv._dummy, srv._dummy,
+        spec((), i32), spec((srv.m, 1), jnp.bool_), srv.layer_ids, 0,
+        "all").as_text()
     return round_txt, prefill_txt
 
 

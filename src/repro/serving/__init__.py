@@ -18,9 +18,12 @@ from repro.serving.kv_cache import (SUPPORTED_KINDS, CachePool, PagePool,
                                     make_paged_decode_step,
                                     make_paged_prefill_step,
                                     make_paged_round_step,
+                                    make_paged_row_prefill_step,
                                     make_pool_decode_step,
                                     make_pool_prefill_step,
-                                    make_pool_round_step, new_block_cache,
+                                    make_pool_round_step,
+                                    make_pool_row_prefill_step,
+                                    new_block_cache,
                                     new_cache_pool_tree, new_paged_pool_tree,
                                     new_state_pool_tree, pages_for,
                                     state_spec_for, state_specs,
@@ -38,8 +41,9 @@ __all__ = ["AdmissionScheduler", "BlockServer", "CachePool",
            "ServedRequest", "StateSpec", "as_device_group", "bucket_for",
            "default_prefill_buckets", "generate", "recovery_replay_cost",
            "kind_runs", "make_paged_decode_step", "make_paged_prefill_step",
-           "make_paged_round_step", "make_pool_decode_step",
-           "make_pool_prefill_step", "make_pool_round_step",
+           "make_paged_round_step", "make_paged_row_prefill_step",
+           "make_pool_decode_step", "make_pool_prefill_step",
+           "make_pool_round_step", "make_pool_row_prefill_step",
            "make_round_tail", "make_sampler", "new_block_cache",
            "new_cache_pool_tree", "new_paged_pool_tree",
            "new_state_pool_tree", "pages_for", "state_spec_for",

@@ -71,11 +71,11 @@ from repro.serving.faults import (FailureDetector, FaultPlan,
 from repro.serving.kv_cache import (CachePool, bucket_for,
                                     default_prefill_buckets, kind_runs,
                                     make_paged_decode_step,
-                                    make_paged_prefill_step,
                                     make_paged_round_step,
+                                    make_paged_row_prefill_step,
                                     make_pool_decode_step,
-                                    make_pool_prefill_step,
                                     make_pool_round_step,
+                                    make_pool_row_prefill_step,
                                     make_prefill_block, pages_for,
                                     state_specs)
 from repro.serving.sampling import (SamplingSpec, make_round_tail,
@@ -171,11 +171,11 @@ class BlockServer:
 
     The hosted range may mix block families; ``self.kinds`` is its static
     per-layer kind tuple and ``self.runs`` the contiguous same-kind runs
-    the pooled steps scan over.  Exposes two pooled compute entry points,
-    both vmapped over the pool's rows so they trace once per server:
-    :meth:`decode_rows` (one token for every masked row) and
-    :meth:`prefill_rows` (one padded prompt chunk for every masked row —
-    the bucket-group prefill path).
+    the pooled steps scan over.  Exposes the pooled compute entry points,
+    each tracing once per server (and, for prefill, per chunk width):
+    :meth:`round_rows` / :meth:`decode_rows` (one token for every masked
+    row) and :meth:`prefill_row` (one session's padded prompt chunk on its
+    own pool row, indexed into the donated pool).
     """
 
     def __init__(self, sid: int, cfg: ModelConfig, params, a: int, m: int,
@@ -264,17 +264,15 @@ class BlockServer:
             self._round_step = make_paged_round_step(cfg, self.kinds,
                                                      backend, page_size,
                                                      mesh, frozen)
-            self._prefill_pool = make_paged_prefill_step(cfg, self.kinds,
-                                                         backend, page_size,
-                                                         mesh, frozen)
+            self._prefill_row = make_paged_row_prefill_step(
+                cfg, self.kinds, backend, page_size, mesh, frozen)
         else:
             self._step = make_pool_decode_step(cfg, self.kinds, backend,
                                                mesh, frozen)
             self._round_step = make_pool_round_step(cfg, self.kinds,
                                                     backend, mesh, frozen)
-            self._prefill_pool = make_pool_prefill_step(cfg, self.kinds,
-                                                        backend, mesh,
-                                                        frozen)
+            self._prefill_row = make_pool_row_prefill_step(
+                cfg, self.kinds, backend, mesh, frozen)
         self._prefill_blocks = {k: make_prefill_block(cfg, k, backend)
                                 for k in set(self.kinds)}
         # constant-shape filler for unused emb0/enc_rows step inputs, so the
@@ -342,23 +340,32 @@ class BlockServer:
                                       entries, S)
         return h
 
-    def prefill_rows(self, h_rows, layer_active, offset: int = 0,
-                     phase: str = "all", emb0_rows=None, enc_rows=None):
-        """THE batched prefill: one jitted call prefills a (padded) prompt
-        chunk starting at ``offset`` for every masked row, writing the
-        chunk's state into the pool.  ``phase`` selects encoder vs
-        non-encoder runs for enc-dec stacks (see make_pool_prefill_step)."""
+    def hop_layers(self, lo: int, hi: int):
+        """(m, 1) layer mask of one row through absolute blocks [lo, hi)."""
+        mask = np.zeros((self.m, 1), bool)
+        mask[lo - self.a: hi - self.a] = True
+        return jnp.asarray(mask)
+
+    def prefill_row(self, h, row: int, layer_active, offset: int = 0,
+                    phase: str = "all", emb0=None, enc=None):
+        """THE prefill dispatch: one session's (padded) prompt chunk ``h``
+        (1, T_chunk, d) starting at ``offset``, through the layers
+        ``layer_active`` (m, 1) marks, computed on pool row ``row`` alone
+        and written into the donated pool in place (see
+        make_pool_row_prefill_step).  ``phase`` selects encoder vs
+        non-encoder runs for enc-dec stacks; ``emb0`` / ``enc``: the
+        session's (1, ., d) shared-attention / encoder inputs."""
         assert self.alive, f"server {self.sid} is dead"
-        args = self._local(h_rows,
-                           self._dummy if emb0_rows is None else emb0_rows,
-                           self._dummy if enc_rows is None else enc_rows,
-                           layer_active) + (self.layer_ids, offset, phase)
+        args = self._local(h, self._dummy if emb0 is None else emb0,
+                           self._dummy if enc is None else enc,
+                           np.int32(row), layer_active) + (
+                               self.layer_ids, offset, phase)
         if self.cache_layout == "paged":
-            h_out, self.pool.tree = self._prefill_pool(
+            h_out, self.pool.tree = self._prefill_row(
                 self.run_params, self.shared, self.pool.tree,
                 self.pool.page_table(), *args)
         else:
-            h_out, self.pool.tree = self._prefill_pool(
+            h_out, self.pool.tree = self._prefill_row(
                 self.run_params, self.shared, self.pool.tree, *args)
         return h_out
 
@@ -467,8 +474,8 @@ class BlockServer:
 @dataclass
 class _PrefillGroup:
     """Co-admitted sessions sharing one route, one prompt-length bucket and
-    (enc-dec) one encoder length, prefilled together in chunk rounds
-    through the pooled prefill step.
+    (enc-dec) one encoder length, prefilled in the same chunk rounds, each
+    member on its own pool row through the row-indexed prefill step.
 
     ``bucket is None`` marks a chunked group: prompts longer than the
     largest bucket, processed in max-bucket-sized chunks that interleave
@@ -982,28 +989,20 @@ class GeoServingSystem:
             if e >= self._n_enc:
                 break
             srv = self.servers[j]
-            lo, hi = e, min(e + k, self._n_enc)
-            N = srv.pool.n_rows
-            d = active[0]._h.shape[-1]
-            h_buf = np.zeros((N, g.enc_len, d), np.asarray(active[0]._h).dtype)
-            mask = np.zeros((srv.m, N), bool)
+            layers = srv.hop_layers(e, min(e + k, self._n_enc))
             for s in active:
-                row = srv.pool.rows[s.sid]
                 g.enc_inputs[s.sid][hop] = s._h
-                h_buf[row] = np.asarray(s._h[0])
-                mask[lo - srv.a: hi - srv.a, row] = True
-            h_out = srv.prefill_rows(jnp.asarray(h_buf), jnp.asarray(mask),
-                                     offset=0, phase="enc")
-            for s in active:
-                s._h = h_out[srv.pool.rows[s.sid]][None]
+                s._h = srv.prefill_row(s._h, srv.pool.rows[s.sid], layers,
+                                       phase="enc")
             e += k
         for s in active:
             s.enc_out = s._h
 
     def _prefill_group_round(self, g: _PrefillGroup) -> List[int]:
         """One chunk round for one bucket group: embed the (padded) token
-        chunk of every member, run the pooled prefill step per hop, account
-        the virtual clock, and finalize members whose prompt completed.
+        chunk of every member, dispatch the row-indexed prefill step once
+        per member per hop, account the virtual clock, and finalize members
+        whose prompt completed.
 
         A route server lost mid-prefill (dead, crashed, or wiped by a
         rejoin) fails the group's in-flight members with a machine-readable
@@ -1055,22 +1054,26 @@ class GeoServingSystem:
             srv = self.servers[j]
             lo, hi = max(e, self._n_enc), e + k
             if lo < hi:  # hop hosts decode-phase blocks
-                N = srv.pool.n_rows
                 with tracing.span("prefill.hop") as sp:
                     if sp:
-                        sp.set(server=j, rows=N, t_pad=t_pad, m=srv.m,
+                        sp.set(server=j, rows=len(active), t_pad=t_pad,
+                               m=srv.m,
                                tok_layers_useful=(hi - lo) * sum(
                                    spans.values()),
-                               tok_layers_computed=N * t_pad * srv.m)
-                    with tracing.span("prefill.stage"):
-                        h_rows, mask, emb0_rows, enc_rows = \
-                            self._prefill_hop_inputs(g, hop, active, spans,
-                                                     srv, lo, hi, t_pad)
-                    h_out = srv.prefill_rows(
-                        h_rows, mask, offset=g.offset, phase=phase,
-                        emb0_rows=emb0_rows, enc_rows=enc_rows)
+                               tok_layers_computed=len(active) * t_pad
+                               * srv.m)
+                    layers = srv.hop_layers(lo, hi)
                     for s in active:
-                        s._h = h_out[srv.pool.rows[s.sid]][None]
+                        # client-side failover cache: the UNPADDED chunk
+                        # entering this hop (stitched to the full prompt at
+                        # completion)
+                        g.hop_chunks[s.sid][hop].append(
+                            s._h[:, : spans[s.sid]])
+                        s._h = srv.prefill_row(
+                            s._h, srv.pool.rows[s.sid], layers,
+                            offset=g.offset, phase=phase,
+                            emb0=s._emb0 if self._needs_emb0 else None,
+                            enc=s.enc_out if self._is_enc_dec else None)
             # Virtual clock, consistent with eq. (1): the group's chunk
             # travels the hop as ONE message — its members share a single
             # RTT — and each session is charged its own (weighted) k·τ^I of
@@ -1114,41 +1117,6 @@ class GeoServingSystem:
                                                spans[s.sid]])
                 done.append(s.sid)
         return done
-
-    def _prefill_hop_inputs(self, g: _PrefillGroup, hop: int,
-                            active: List[EngineSession],
-                            spans: Dict[int, int], srv: BlockServer,
-                            lo: int, hi: int, t_pad: int):
-        """The device inputs of one prefill hop: every member's hidden rows
-        (and emb0 / encoder rows) staged into (N, t_pad, d) host buffers at
-        its pool row, uploaded with the layer mask as
-        (h_rows, layer_active, emb0_rows, enc_rows).  Also records each
-        member's unpadded chunk in its client-side failover cache."""
-        N = srv.pool.n_rows
-        d = active[0]._h.shape[-1]
-        dt = np.asarray(active[0]._h).dtype
-        h_buf = np.zeros((N, t_pad, d), dt)
-        emb0_buf = (np.zeros((N, t_pad, d), dt)
-                    if self._needs_emb0 else None)
-        enc_buf = None
-        if self._is_enc_dec:
-            enc_buf = np.zeros((N, g.enc_len, d),
-                               np.asarray(active[0].enc_out).dtype)
-        mask = np.zeros((srv.m, N), bool)
-        for s in active:
-            row = srv.pool.rows[s.sid]
-            # client-side failover cache: the UNPADDED chunk entering this
-            # hop (stitched to the full prompt at completion)
-            g.hop_chunks[s.sid][hop].append(s._h[:, : spans[s.sid]])
-            h_buf[row] = np.asarray(s._h[0])
-            if emb0_buf is not None:
-                emb0_buf[row] = np.asarray(s._emb0[0])
-            if enc_buf is not None:
-                enc_buf[row] = np.asarray(s.enc_out[0])
-            mask[lo - srv.a: hi - srv.a, row] = True
-        return (jnp.asarray(h_buf), jnp.asarray(mask),
-                None if emb0_buf is None else jnp.asarray(emb0_buf),
-                None if enc_buf is None else jnp.asarray(enc_buf))
 
     def _prefill_serial(self, sess: EngineSession):
         """Legacy one-session-per-call prefill — the exact-length reference
@@ -2151,33 +2119,26 @@ class GeoServingSystem:
 
     def _replay_chunked(self, sess: EngineSession, srv: BlockServer,
                         lo: int, hi: int, h_full, phase: str,
-                        enc_rows=None, emb0_full=None):
-        """Replay blocks [lo, hi) of one session's prompt through the
-        pooled prefill programs, following its deterministic chunk plan —
-        the ONE chunk-replay loop shared by the single-phase and enc-dec
-        failover paths."""
-        N = srv.pool.n_rows
+                        enc=None, emb0_full=None):
+        """Replay blocks [lo, hi) of one session's prompt through the row
+        prefill step that built its caches, following its deterministic
+        chunk plan — the ONE chunk-replay loop shared by the single-phase
+        and enc-dec failover paths."""
         d = h_full.shape[-1]
         row = srv.pool.rows[sess.sid]
-        mask = np.zeros((srv.m, N), bool)
-        mask[lo - srv.a: hi - srv.a, row] = True
-        mask = jnp.asarray(mask)
+        layers = srv.hop_layers(lo, hi)
         outs = []
         for off, span, t_pad in self._prefill_plan(h_full.shape[1]):
             chunk = h_full[:, off: off + span]
             if t_pad > span:
                 chunk = jnp.concatenate(
                     [chunk, jnp.zeros((1, t_pad - span, d), chunk.dtype)], 1)
-            h_buf = jnp.zeros((N, t_pad, d), chunk.dtype).at[row].set(
-                chunk[0])
-            emb0_rows = None
+            emb0 = None
             if emb0_full is not None:  # recurrent plan: one exact chunk
-                emb0_rows = jnp.zeros((N, t_pad, d),
-                                      emb0_full.dtype).at[row].set(
-                    emb0_full[0, off: off + t_pad])
-            h_out = srv.prefill_rows(h_buf, mask, offset=off, phase=phase,
-                                     emb0_rows=emb0_rows, enc_rows=enc_rows)
-            outs.append(h_out[row][None, :span])
+                emb0 = emb0_full[:, off: off + t_pad]
+            h_out = srv.prefill_row(chunk, row, layers, offset=off,
+                                    phase=phase, emb0=emb0, enc=enc)
+            outs.append(h_out[:, :span])
         return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
     def _replay_prefill_encdec(self, sess: EngineSession, j: int, lo: int,
@@ -2196,15 +2157,9 @@ class GeoServingSystem:
                 hs_enc = srv.prefill_range(sess.sid, hs_enc, elo, ehi,
                                            jnp.arange(hs_enc.shape[1]))
             else:
-                N = srv.pool.n_rows
-                row = srv.pool.rows[sess.sid]
-                mask = np.zeros((srv.m, N), bool)
-                mask[elo - srv.a: ehi - srv.a, row] = True
-                h_buf = jnp.zeros((N,) + hs_enc.shape[1:],
-                                  hs_enc.dtype).at[row].set(hs_enc[0])
-                h_out = srv.prefill_rows(h_buf, jnp.asarray(mask),
-                                         offset=0, phase="enc")
-                hs_enc = h_out[row][None]
+                hs_enc = srv.prefill_row(hs_enc, srv.pool.rows[sess.sid],
+                                         srv.hop_layers(elo, ehi),
+                                         phase="enc")
         if hi > n_enc and hs_dec is not None:
             dlo = max(lo, n_enc)
             if self.prefill_mode == "serial":
@@ -2212,12 +2167,8 @@ class GeoServingSystem:
                     sess.sid, hs_dec, dlo, hi,
                     jnp.arange(hs_dec.shape[1]), enc_h=sess.enc_out)
             else:
-                row = srv.pool.rows[sess.sid]
-                enc_rows = jnp.zeros(
-                    (srv.pool.n_rows,) + sess.enc_out.shape[1:],
-                    sess.enc_out.dtype).at[row].set(sess.enc_out[0])
                 hs_dec = self._replay_chunked(sess, srv, dlo, hi, hs_dec,
-                                              "dec", enc_rows=enc_rows)
+                                              "dec", enc=sess.enc_out)
         return hs_enc, hs_dec
 
     @staticmethod

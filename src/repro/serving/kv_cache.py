@@ -736,9 +736,9 @@ def _masked_ranged_write(cache, chunk, active, keys, lo, span):
 
 def _prefill_step_body(cfg: ModelConfig, kinds: Tuple[str, ...],
                        backend: str):
-    """The UNJITTED multi-session prefill step body shared by
-    :func:`make_pool_prefill_step` (slab layout) and
-    :func:`make_paged_prefill_step` (which wraps it in the page
+    """The UNJITTED prefill step body shared by :func:`make_pool_prefill_step`
+    (every pool row), :func:`make_pool_row_prefill_step` (one indexed row,
+    the served path) and their paged twins (which wrap it in the page
     gather/scatter).
 
     pstep(run_params, shared_params, pool_trees, h, emb0, enc_rows,
@@ -747,9 +747,11 @@ def _prefill_step_body(cfg: ModelConfig, kinds: Tuple[str, ...],
     * ``run_params``: tuple of per-run stacked block params (axis 0 = the
       run's layers); ``shared_params``: zamba2's parameter-shared attention
       block (None otherwise),
-    * ``pool_trees``: tuple of per-run state subtrees (see ``CachePool``),
-    * ``h``: (n_rows, T_chunk, d) right-padded hidden rows — one row per
-      co-admitted session of a bucket group (same row indices as decode),
+    * ``pool_trees``: tuple of per-run state subtrees (see ``CachePool``)
+      whose row axis is the ``n_rows`` this call computes: the whole pool,
+      or the one-row slab the row step takes out of it,
+    * ``h``: (n_rows, T_chunk, d) right-padded hidden rows, row for row
+      with ``pool_trees``,
     * ``emb0``: (n_rows, T_chunk, d) original embeddings for shared-attn
       blocks (a dummy leaf when no block needs it),
     * ``enc_rows``: (n_rows, T_enc, d) encoder outputs for cross-attention
@@ -975,6 +977,70 @@ def make_pool_prefill_step(cfg: ModelConfig, kinds: Tuple[str, ...],
         return rows(h, "batch", None, None), pools(new_trees)
 
     return jax.jit(step, static_argnums=(8, 9), donate_argnums=(2,))
+
+
+def _take_row(pool_trees, row, whole=frozenset()):
+    """Row ``row``'s slab of every pooled leaf, (layers, 1, ...), by a
+    dynamic index on the row axis; leaves named in ``whole`` (the paged
+    layout's physical page arrays) pass through as they are."""
+    return tuple(
+        {k: x if k in whole else jax.lax.dynamic_slice_in_dim(x, row, 1, 1)
+         for k, x in t.items()}
+        for t in pool_trees)
+
+
+def _put_row(pool_trees, row_trees, row, whole=frozenset()):
+    """Write one-row slabs back at ``row`` by a dynamic update on the row
+    axis (in place into a donated pool); leaves named in ``whole`` are
+    taken from ``row_trees`` as they are."""
+    return tuple(
+        {k: r[k] if k in whole else jax.lax.dynamic_update_slice_in_dim(
+            x, r[k].astype(x.dtype), row, 1)
+         for k, x in t.items()}
+        for t, r in zip(pool_trees, row_trees))
+
+
+@functools.lru_cache(maxsize=None)
+def make_pool_row_prefill_step(cfg: ModelConfig, kinds: Tuple[str, ...],
+                               backend: str = "xla", mesh=None, rules=None):
+    """THE served prefill dispatch: one session's prompt chunk, computed on
+    its own pool row alone, indexed into the donated pool.
+
+    step(run_params, shared_params, pool_trees, h, emb0, enc_rows, row,
+         layer_active, layer_ids, offset, phase) -> (h, pool_trees)
+
+    * ``h``: (1, T_chunk, d) the session's right-padded hidden rows;
+      ``emb0`` (1, T_chunk, d) / ``enc_rows`` (1, T_enc, d) or the dummy,
+    * ``row``: int32 scalar, the session's pool row,
+    * ``layer_active``: (n_layers, 1) bool — the hop's layers,
+    * the rest as in :func:`_prefill_step_body`.
+
+    One jitted program: take the row's slab out of every pool leaf, run
+    :func:`_prefill_step_body` over that one-row sub-pool, write it back
+    in place.  No other row is read or rewritten.  The width is always one
+    row, so a session runs the same program (one per ``T_chunk``) alone,
+    in a group of any size and in failover replay: bit-identical by
+    construction.  Pool trees donated, as in :func:`make_pool_prefill_step`;
+    ``mesh``/``rules`` likewise (the one-row inputs pinned replicated)."""
+    body = _prefill_step_body(cfg, kinds, backend)
+    cons = None if mesh is None else _mesh_constraints(mesh, rules)
+
+    def step(run_params, shared_params, pool_trees, h, emb0, enc_rows, row,
+             layer_active, layer_ids, offset, phase):
+        if cons is not None:
+            pools, _rows, repl = cons
+            pool_trees = pools(pool_trees)
+            h, emb0, enc_rows = repl(h), repl(emb0), repl(enc_rows)
+            layer_active, layer_ids = repl(layer_active), repl(layer_ids)
+        h, sub = body(run_params, shared_params, _take_row(pool_trees, row),
+                      h, emb0, enc_rows, layer_active, layer_ids, offset,
+                      phase)
+        new_trees = _put_row(pool_trees, sub, row)
+        if cons is not None:
+            h, new_trees = cons[2](h), cons[0](new_trees)
+        return h, new_trees
+
+    return jax.jit(step, static_argnums=(9, 10), donate_argnums=(2,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -1458,6 +1524,41 @@ def make_paged_prefill_step(cfg: ModelConfig, kinds: Tuple[str, ...],
         return h_out, new_trees
 
     return jax.jit(step, static_argnums=(9, 10), donate_argnums=(2,))
+
+
+@functools.lru_cache(maxsize=None)
+def make_paged_row_prefill_step(cfg: ModelConfig, kinds: Tuple[str, ...],
+                                backend: str = "xla", page_size: int = 16,
+                                mesh=None, rules=None):
+    """Paged twin of :func:`make_pool_row_prefill_step` (page table inserted
+    after the pool trees; ``offset``/``phase`` stay static): gathers the
+    row's pages alone through its page-table row, runs the same body on
+    that one-row scratch, scatters those pages back, and writes the row's
+    row-resident leaves in place.  Other rows' pages are not touched."""
+    body = _prefill_step_body(cfg, kinds, backend)
+    runs = kind_runs(kinds)
+    cons = None if mesh is None else _mesh_constraints(mesh, rules)
+
+    def step(run_params, shared_params, pool_trees, page_table, h, emb0,
+             enc_rows, row, layer_active, layer_ids, offset, phase):
+        if cons is not None:
+            pools, _rows, repl = cons
+            pool_trees, page_table = pools(pool_trees), repl(page_table)
+            h, emb0, enc_rows = repl(h), repl(emb0), repl(enc_rows)
+            layer_active, layer_ids = repl(layer_active), repl(layer_ids)
+        pages = jax.lax.dynamic_slice_in_dim(page_table, row, 1, 0)
+        sub = _take_row(pool_trees, row, _SELF_KV_KEYS)
+        scratch = _gather_paged(runs, sub, pages, page_size)
+        h, new_scratch = body(run_params, shared_params, scratch, h, emb0,
+                              enc_rows, layer_active, layer_ids, offset,
+                              phase)
+        sub = _scatter_paged(runs, sub, new_scratch, pages, page_size)
+        new_trees = _put_row(pool_trees, sub, row, _SELF_KV_KEYS)
+        if cons is not None:
+            h, new_trees = cons[2](h), cons[0](new_trees)
+        return h, new_trees
+
+    return jax.jit(step, static_argnums=(10, 11), donate_argnums=(2,))
 
 
 @functools.lru_cache(maxsize=None)

@@ -1,9 +1,10 @@
 """Compile guard for the TPU v5e: the four Pallas kernels at the widths of
-the models that use them, and one server's pooled round and prefill steps
-of OLMo-1B at its published widths, compiled for a described (not
-attached) v5e.  Each asserts that Mosaic kernels (``tpu_custom_call``) are
-in the compiled program; one more asserts that the XLA round step writes
-its donated pool in place.  Nothing runs: a pass says the chip's compiler
+the models that use them, and one server's pooled round, prefill and
+row-indexed prefill steps of OLMo-1B at its published widths, compiled
+for a described (not attached) v5e.  Each asserts that Mosaic kernels
+(``tpu_custom_call``) are in the compiled program; two more assert that
+the XLA round step and row prefill step write their donated pool in
+place.  Nothing runs: a pass says the chip's compiler
 accepts the program, not that its results are right.
 
 The topology is described inside a module fixture, never at import: only
@@ -27,7 +28,9 @@ from repro.kernels.wkv6 import ops as wkv6_ops
 from repro.models import init_params_shapes
 from repro.models.model import block_param_range
 from repro.serving.kv_cache import (make_pool_prefill_step,
-                                    make_pool_round_step, new_state_pool_tree)
+                                    make_pool_round_step,
+                                    make_pool_row_prefill_step,
+                                    new_state_pool_tree)
 
 B, T = 4, 1024  # decode rows and cache length
 N_ROWS, W, BLOCKS = 8, 8, 4  # one server of the chip smoke's fleet
@@ -126,17 +129,27 @@ def _lower_round(one_chip, backend):
         lids)
 
 
-@pytest.mark.parametrize("step", ["round", "prefill"])
+def _lower_row_prefill(one_chip, backend, bucket):
+    cfg, kinds, params, pool, spec = _olmo_server(one_chip)
+    lids, dummy = spec((BLOCKS,), I32), spec((1, 1, 1), F32)
+    return pool, make_pool_row_prefill_step(cfg, kinds, backend).lower(
+        params, None, pool, spec((1, bucket, cfg.d_model), BF16), dummy,
+        dummy, spec((), I32), spec((BLOCKS, 1), jnp.bool_), lids, 0, "all")
+
+
+@pytest.mark.parametrize("step", ["round", "prefill", "row_prefill"])
 def test_olmo_pooled_step_compiles_for_v5e(one_chip, monkeypatch, step):
     """One server's pooled step with ``backend="pallas"``: four OLMo-1B
     blocks, 8 rows of 1024-token bf16 cache, round width 8, and a
-    512-token prefill bucket."""
+    512-token prefill bucket (over every row, or over one indexed row)."""
     # the kernels pick interpret mode from the default backend, which is
     # the CPU here: compile them for the described chip instead
     for mod in (decode_ops, flash_ops, ssd_ops, wkv6_ops):
         monkeypatch.setattr(mod, "default_interpret", lambda: False)
     if step == "round":
         _, lowered = _lower_round(one_chip, "pallas")
+    elif step == "row_prefill":
+        _, lowered = _lower_row_prefill(one_chip, "pallas", 512)
     else:
         cfg, kinds, params, pool, spec = _olmo_server(one_chip)
         mask = spec((BLOCKS, N_ROWS), jnp.bool_)
@@ -148,16 +161,29 @@ def test_olmo_pooled_step_compiles_for_v5e(one_chip, monkeypatch, step):
     assert "tpu_custom_call" in lowered.compile().as_text()
 
 
-def test_olmo_round_step_writes_pool_in_place(one_chip):
-    """The round step on the XLA backend writes the donated pool in place:
-    its scratch memory is a small fraction of the pool, and no copy of the
-    whole pool is made."""
-    pool, lowered = _lower_round(one_chip, "xla")
+def _assert_in_place(pool, lowered, temp_share):
+    """The compiled step's scratch memory is under ``temp_share`` of the
+    pool, and it makes no copy of a whole pool leaf."""
     compiled = lowered.compile()
     leaves = jax.tree.leaves(pool)
     pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < 0.1 * pool_bytes, (temp, pool_bytes)
+    assert temp < temp_share * pool_bytes, (temp, pool_bytes)
     shape = ",".join(map(str, leaves[0].shape))
     assert not re.search(rf"= bf16\[{shape}\]\S* copy\(",
                          compiled.as_text())
+
+
+def test_olmo_round_step_writes_pool_in_place(one_chip):
+    """The round step on the XLA backend writes the donated pool in place:
+    its scratch memory is a small fraction of the pool, and no copy of the
+    whole pool is made."""
+    _assert_in_place(*_lower_round(one_chip, "xla"), 0.1)
+
+
+def test_olmo_row_prefill_step_writes_pool_in_place(one_chip):
+    """The row prefill step on the XLA backend, at the 1024-token bucket,
+    writes its one row into the donated pool in place: no copy of the
+    whole pool, and scratch for one row's activations (68 MB against the
+    N-row step's 1.18 GB over 8 rows), under half the 268 MB pool."""
+    _assert_in_place(*_lower_row_prefill(one_chip, "xla", 1024), 0.5)

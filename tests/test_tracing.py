@@ -111,7 +111,7 @@ def test_engine_spans_and_row_counts(profiler):
     lens = [20, 30, 9]  # buckets 32, 32 and 16: two groups
     prefill_calls, hop_calls = [], []
     for srv in system.servers.values():
-        _spy(srv, "prefill_rows", prefill_calls)
+        _spy(srv, "prefill_row", prefill_calls)
         _spy(srv, "round_rows", hop_calls)
     with profiler:
         route, *_ = ctl.admit(0, 0.0)
@@ -131,23 +131,33 @@ def test_engine_spans_and_row_counts(profiler):
                                     "sids": sids}
     assert by["retire"][0].attrs == {"sid": sids[0]}
 
-    # prefill: one hop span per (group, hop), each with its staging
+    # prefill: one hop span per (group, hop), inside it one one-row
+    # dispatch per member and nothing staged on the host
     (pr,) = by["prefill_round"]
     assert pr.attrs == {"groups": 2}
     hops = [s for s in _children(spans, pr) if s.name == "prefill.hop"]
-    assert len(hops) == 2 * len(route.servers) == len(prefill_calls)
+    assert len(hops) == 2 * len(route.servers)
     span_of = {sid: n for sid, n in zip(sids, lens)}
-    for hop, (srv, args, rows) in zip(hops, prefill_calls):
-        h_rows, mask = np.asarray(args[0]), np.asarray(args[1])
-        assert [c.name for c in _children(spans, hop)] == ["prefill.stage"]
-        assert hop.attrs["server"] == srv.sid
-        assert (hop.attrs["rows"], hop.attrs["t_pad"]) == h_rows.shape[:2]
-        assert hop.attrs["m"] == srv.m == mask.shape[0]
-        useful = sum(int(mask[:, rows[sid]].sum()) * n
-                     for sid, n in span_of.items())
+    members = {32: sids[:2], 16: sids[2:]}
+    calls = iter(prefill_calls)
+    for hop in hops:
+        assert _children(spans, hop) == []
+        group = members[hop.attrs["t_pad"]]
+        assert hop.attrs["rows"] == len(group)
+        useful = 0
+        for sid in group:
+            srv, args, rows = next(calls)
+            h, layer_active = np.asarray(args[0]), np.asarray(args[2])
+            assert args[1] == rows[sid]
+            assert hop.attrs["server"] == srv.sid
+            assert h.shape[:2] == (1, hop.attrs["t_pad"])
+            assert layer_active.shape == (hop.attrs["m"], 1)
+            assert hop.attrs["m"] == srv.m
+            useful += int(layer_active.sum()) * span_of[sid]
         assert hop.attrs["tok_layers_useful"] == useful
         assert hop.attrs["tok_layers_computed"] == \
-            mask.shape[1] * h_rows.shape[1] * mask.shape[0]
+            len(group) * hop.attrs["t_pad"] * hop.attrs["m"]
+    assert next(calls, None) is None
     assert sum(h.attrs["tok_layers_useful"] for h in hops) == \
         sum(lens) * system.cfg.n_layers
     fin = [s for s in _children(spans, pr) if s.name == "prefill.finalize"]
@@ -183,7 +193,7 @@ def test_pooled_programs_compile_under_stable_names():
     system, _ = _tiny_system()
     srv = next(iter(system.servers.values()))
     seen = {}
-    for obj, attr in ((srv, "_round_step"), (srv, "_prefill_pool"),
+    for obj, attr in ((srv, "_round_step"), (srv, "_prefill_row"),
                       (srv, "_step"), (system, "_round_tail")):
         fn, calls = getattr(obj, attr), []
         seen[attr] = (fn, calls)
@@ -203,6 +213,6 @@ def test_pooled_programs_compile_under_stable_names():
     system.decode_round()  # host-staged: the pooled decode step
     names = {attr: set(calls) for attr, (_, calls) in seen.items()}
     assert names == {"_round_step": {"jit_hop"},
-                     "_prefill_pool": {"jit_step"},
+                     "_prefill_row": {"jit_step"},
                      "_step": {"jit_decode_step"},
                      "_round_tail": {"jit_tail"}}
